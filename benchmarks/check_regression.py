@@ -11,10 +11,7 @@ any regresses beyond the tolerance:
                                 exhaustive; deterministic), latency_ratio
                                 (pruned vs exhaustive top-k, same run),
                                 fused.latency_ratio (fused dispatch vs the
-                                kernel multi-phase pipeline, same run) and
-                                fused.roofline.fraction_of_hbm_roof (achieved
-                                bandwidth vs the HBM roof; gated as a floor —
-                                higher is better)
+                                kernel multi-phase pipeline, same run)
   BENCH_serve_latency.json      trace_overhead_ratio (traced vs untraced
                                 closed-loop service time through the sched/
                                 process-replica path — TraceContext IPC,
@@ -104,17 +101,6 @@ METRICS = [
     ("BENCH_dispatch_overhead.json", "bridge_over_kernel", 1.0),
 ]
 
-# (file, dotted-path of a higher-is-better metric, absolute cap the limit is
-# never raised above).  Achieved-bandwidth fractions are wall-clock-derived
-# and shift with the runner's memory subsystem, so the cap — not the
-# baseline — is the portable bar: the fused dispatch collapsing to ~zero
-# achieved bandwidth (e.g. silently degrading to per-query dispatches with
-# the same traffic) fails on any machine
-FLOOR_METRICS = [
-    ("BENCH_ranked_topk.json", "fused.roofline.fraction_of_hbm_roof", 1e-5),
-]
-
-
 def _lookup(obj, dotted: str):
     for part in dotted.split("."):
         if not isinstance(obj, dict) or part not in obj:
@@ -158,26 +144,6 @@ def check(baseline_dir: str, fresh_dir: str, tolerance: float = TOLERANCE) -> li
         if f > limit:
             failures.append(f"{fname}:{metric} regressed {f:.4f} > {limit:.4f} (baseline {b:.4f})")
 
-    for fname, metric, cap in FLOOR_METRICS:
-        base, fresh = load(baseline_dir, fname), load(fresh_dir, fname)
-        if base is None:
-            print(f"SKIP {fname}:{metric} — no committed baseline")
-            continue
-        if fresh is None:
-            failures.append(f"{fname} missing from fresh results")
-            continue
-        b, f = _lookup(base, metric), _lookup(fresh, metric)
-        if b is None:
-            print(f"SKIP {fname}:{metric} — metric absent in baseline")
-            continue
-        if f is None:
-            failures.append(f"{fname}:{metric} absent in fresh results")
-            continue
-        limit = min(b * (1 - tolerance), cap)
-        verdict = "FAIL" if f < limit else "ok"
-        print(f"{verdict:4s} {fname}:{metric}  baseline={b:.3e}  fresh={f:.3e}  limit={limit:.3e} (floor)")
-        if f < limit:
-            failures.append(f"{fname}:{metric} collapsed {f:.3e} < {limit:.3e} (baseline {b:.3e})")
     return failures
 
 

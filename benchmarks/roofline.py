@@ -1,10 +1,12 @@
 """Roofline analysis from dry-run artifacts (EXPERIMENTS.md §Roofline).
 
-Hardware model: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware model: the per-chip peaks of ``PEAKS``, keyed by the device kind
+JAX reports (``jax.devices()[0].device_kind``); a kind that is not in the
+table is an error, never a default.
 Terms (per device, per step):
-  compute_s    = HLO_flops / PEAK_FLOPS
-  memory_s     = HLO_bytes / HBM_BW
-  collective_s = Σ collective bytes / ICI_BW
+  compute_s    = HLO_flops / bf16 peak
+  memory_s     = HLO_bytes / HBM bandwidth
+  collective_s = Σ collective bytes / interconnect bandwidth
 The dominant term is the bottleneck; MODEL_FLOPS/HLO_FLOPS measures how much
 compiled compute is "useful" (catches remat/redundancy waste).
 """
@@ -13,9 +15,30 @@ from __future__ import annotations
 import json
 from typing import Any
 
-PEAK_FLOPS = 197e12  # bf16 per chip
-HBM_BW = 819e9
-ICI_BW = 50e9
+# Published per-chip peaks.  Source: Google Cloud documentation, "TPU v5e"
+# (197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s
+# chip-to-chip interconnect).  The int32 VPU throughput the index kernels
+# use is not published: not measured.
+PEAKS: dict[str, dict[str, float]] = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "int8_ops": 393e12,
+        "hbm_bytes_per_s": 819e9,
+        "ici_bytes_per_s": 1600e9 / 8,
+    },
+}
+
+
+def peaks(device_kind: str) -> dict[str, float]:
+    """The published peaks of one chip of ``device_kind``."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)} (add the chip's peaks to PEAKS with their source)"
+        ) from None
+
 
 # 6·N·D with N = (active) params, D = tokens per step — per arch × shape
 ARCH_PARAMS = {  # total / active parameter counts
@@ -54,12 +77,13 @@ SHAPE_DIMS = {
 def analyze(record: dict[str, Any]) -> dict[str, Any] | None:
     if record.get("status") != "ok":
         return None
+    peak = peaks(record.get("device_kind", ""))
     flops = record["flops_per_device"]
     mem_bytes = record["bytes_per_device"]
     coll = sum(record["collective_bytes_per_device"].values())
-    compute_s = flops / PEAK_FLOPS
-    memory_s = mem_bytes / HBM_BW
-    collective_s = coll / ICI_BW
+    compute_s = flops / peak["bf16_flops"]
+    memory_s = mem_bytes / peak["hbm_bytes_per_s"]
+    collective_s = coll / peak["ici_bytes_per_s"]
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     dominant = max(terms, key=terms.get)
     out = dict(record)
@@ -80,7 +104,7 @@ def analyze(record: dict[str, Any]) -> dict[str, Any] | None:
         if mf:
             out["model_flops_per_device"] = mf
             out["useful_flop_frac"] = mf / max(flops, 1.0)
-            out["mfu_upper_bound"] = mf / PEAK_FLOPS / max(terms.values())
+            out["mfu_upper_bound"] = mf / peak["bf16_flops"] / max(terms.values())
     return out
 
 
@@ -88,14 +112,10 @@ def analyze(record: dict[str, Any]) -> dict[str, Any] | None:
 # Cost model for the fused ranked-query dispatch (kernels.fused_query): the
 # serving engine's RankedStats counts the packed stream bytes its ε-window
 # probe lanes touch and the device array traffic of each dispatch
-# (fused_stream_bytes / fused_device_bytes), and every probe lane costs a
-# near-constant number of integer VPU ops (segment line eval, two word-pair
-# unpacks, compare, accumulate).  Positioning achieved bytes/s against the
-# HBM roof answers the ISSUE's question directly: is the fused path bound by
-# memory bandwidth (good — the paper's compression translates to speed) or
-# still by dispatch/bookkeeping overhead?
-PEAK_INT_OPS = 3.2e12  # rough int32 VPU throughput per chip (8x939 MHz lanes)
-INT_OPS_PER_LANE = 24  # line eval + 2 unpacks + compare + select + accumulate
+# (fused_stream_bytes / fused_device_bytes).  Achieved bytes/s against the
+# chip's HBM roof says whether the fused path is bound by memory bandwidth
+# or by dispatch/bookkeeping overhead.  The lanes' int32 VPU work has no
+# published peak, so its roof is not measured.
 
 
 def index_roofline(
@@ -105,16 +125,18 @@ def index_roofline(
     seconds: float,
     queries: int,
     *,
+    device_kind: str,
     kernel_seconds: float | None = None,
     bridge_seconds: float | None = None,
-) -> dict[str, float]:
+) -> dict[str, Any]:
     """Fused ranked dispatch accounting -> position vs the HBM-bandwidth roof.
 
     ``stream_bytes`` are the index bytes the dispatch's lanes read (the
     paper-facing number: what compression makes small); ``device_bytes`` the
     dispatch's array traffic (what HBM actually moves); ``lanes`` the probe
     lanes evaluated; ``seconds`` the measured wall time of the ranked pass
-    serving ``queries`` queries.
+    serving ``queries`` queries on a chip of ``device_kind`` (``peaks``
+    raises for a kind without published peaks).
 
     When the caller splits the wall into ``kernel_seconds`` (blocked on
     device execution) and ``bridge_seconds`` (host plan/pack/merge),
@@ -123,25 +145,23 @@ def index_roofline(
     not Python; the wall-time figure stays reported as
     ``achieved_bytes_per_s_wall``.
     """
+    hbm = peaks(device_kind)["hbm_bytes_per_s"]
     seconds = max(seconds, 1e-12)
-    memory_s = device_bytes / HBM_BW
-    compute_s = lanes * INT_OPS_PER_LANE / PEAK_INT_OPS
-    roof_s = max(memory_s, compute_s)
+    memory_s = device_bytes / hbm
     exec_s = max(kernel_seconds, 1e-12) if kernel_seconds else seconds
     achieved = device_bytes / exec_s
     out = {
+        "device_kind": device_kind,
         "stream_bytes": int(stream_bytes),
         "device_bytes": int(device_bytes),
         "lanes": int(lanes),
         "seconds": seconds,
         "bytes_per_query": device_bytes / max(queries, 1),
         "hbm_roof_s": memory_s,
-        "int_roof_s": compute_s,
-        "roofline_s": roof_s,
-        "dominant": "memory" if memory_s >= compute_s else "compute",
+        "int_roof_s": "not measured",
         "achieved_bytes_per_s": achieved,
         "achieved_bytes_per_s_wall": device_bytes / seconds,
-        "fraction_of_hbm_roof": achieved / HBM_BW,
+        "fraction_of_hbm_roof": achieved / hbm,
     }
     if kernel_seconds is not None:
         out["kernel_seconds"] = float(kernel_seconds)

@@ -209,10 +209,8 @@ def main():
     # the θ-peel top-k loop runs as ONE jitted dispatch over a per-shard
     # device arena — the impact table is uploaded once per process
     # (residency counters prove it) and the host bridge only pads queries
-    # and extracts results.  Timing the device execution separately from
-    # that bridge is what moved the gated roofline fraction ~25x, from
-    # 1.34e-4 (host-timed, pre-arena) to ~3.4e-3 (device-timed, arena) in
-    # BENCH_ranked_topk.json — see README "Performance tuning"
+    # and extracts results.  RankedStats times the device execution
+    # separately from that bridge (fused_kernel_ns vs fused_bridge_ns)
     fused_eng = BooleanEngine(lb, inv, li_cfg,
                               ServeConfig(ranked=dict(fused_kernel=True)))
     (ftop,) = fused_eng.query_topk(ranked_q, 10)

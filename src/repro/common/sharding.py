@@ -43,66 +43,33 @@ DEFAULT_RULES: dict[str, Any] = {
 
 
 def concrete_mesh(shape: Sequence[int], names: Sequence[str]) -> Mesh:
-    """Version-portable device-mesh constructor.
-
-    jax >= 0.5 wants explicit axis_types (Auto) for the shard_map/pjit mix
-    these modules use; 0.4.x has no AxisType and defaults to the same
-    behaviour.  Tests and launchers build meshes through this so one source
-    tree runs on both."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            tuple(shape), tuple(names), axis_types=(axis_type.Auto,) * len(names)
-        )
-    return jax.make_mesh(tuple(shape), tuple(names))
+    """Device mesh with explicit Auto axis types, the shard_map/pjit mix
+    these modules use.  Tests and launchers build meshes through this."""
+    return jax.make_mesh(
+        tuple(shape), tuple(names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+    )
 
 
 def mesh_context(mesh: Mesh):
-    """`with mesh_context(mesh):` — jax.set_mesh where it exists (>= 0.6),
-    falling back to the legacy Mesh context manager."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+    """`with mesh_context(mesh):` installs the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
-def _shard_map():
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map as legacy_shard_map
-
-    return legacy_shard_map
-
-
-def shard_map(*args, **kwargs):
-    """Version-portable jax.shard_map (jax.experimental.shard_map on 0.4.x)."""
-    return _shard_map()(*args, **kwargs)
-
-
-def axis_size(axis_name: str):
-    """jax.lax.axis_size where it exists; psum(1) inside shard_map otherwise."""
-    fn = getattr(jax.lax, "axis_size", None)
-    return fn(axis_name) if fn is not None else jax.lax.psum(1, axis_name)
+shard_map = jax.shard_map
+axis_size = jax.lax.axis_size
 
 
 def pvary(x, axis_names):
-    """jax.lax.pvary where it exists.  On 0.4.x there is no explicit-varying
-    type system, so marking a value as varying is the identity."""
-    fn = getattr(jax.lax, "pvary", None)
-    return fn(x, axis_names) if fn is not None else x
+    """Mark ``x`` as varying over ``axis_names`` inside shard_map."""
+    return jax.lax.pcast(x, axis_names, to="varying")
 
 
 def abstract_mesh(shape: Sequence[int], names: Sequence[str]):
-    """Version-portable AbstractMesh((16, 16), ("data", "model")) constructor.
-
-    Current JAX (0.4.36+) takes (name, size) pairs in one shape_tuple;
-    later releases moved to split (axis_sizes, axis_names) positionals.
-    Tests and dry-run cells use this so either signature works.
-    """
+    """AbstractMesh((16, 16), ("data", "model")): symbolic axes, no devices."""
     if len(shape) != len(names):
         raise ValueError(f"shape {shape} and names {names} must align")
-    try:
-        return jax.sharding.AbstractMesh(tuple(zip(names, shape)))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(names))
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(names))
 
 
 def _mesh_axes(mesh: Mesh) -> tuple[str, ...]:

@@ -52,13 +52,38 @@ class LearnedBloom:
         )
 
 
+PAIR_CHUNK = 1 << 16  # (term, doc) pairs per logit dispatch: one jit shape
+_pair_logits = jax.jit(membership.pair_logits)
+
+
+def _positive_logits(params: Any, inv: InvertedIndex, terms: np.ndarray) -> np.ndarray:
+    """f-logits of every indexed (t, d) pair of ``terms``, in posting order.
+
+    Pairs go to the device in fixed PAIR_CHUNK slices (the tail zero-padded),
+    so scoring a whole collection compiles one program, whatever its list
+    lengths."""
+    dfs = inv.dfs[terms]
+    term_of = np.repeat(np.asarray(terms, np.int32), dfs)
+    starts = inv.term_offsets[terms]
+    first = np.repeat(np.cumsum(dfs) - dfs, dfs)
+    docs = inv.doc_ids[np.repeat(starts, dfs) + np.arange(len(term_of)) - first]
+    out = np.empty(len(term_of), np.float32)
+    t_buf = np.zeros(PAIR_CHUNK, np.int32)
+    d_buf = np.zeros(PAIR_CHUNK, np.int32)
+    for i in range(0, len(term_of), PAIR_CHUNK):
+        m = min(PAIR_CHUNK, len(term_of) - i)
+        t_buf[:m], t_buf[m:] = term_of[i : i + m], 0
+        d_buf[:m], d_buf[m:] = docs[i : i + m], 0
+        out[i : i + m] = np.asarray(_pair_logits(params, t_buf, d_buf))[:m]
+    return out
+
+
 def fit_thresholds(
     params: Any,
     inv: InvertedIndex,
     *,
     terms: np.ndarray | None = None,
     backup_quantile: float = 0.0,
-    batch_docs: int = 8192,
 ) -> LearnedBloom:
     """Scan indexed positives per term; τ_t = quantile of positive logits.
 
@@ -68,26 +93,28 @@ def fit_thresholds(
     """
     n_terms, n_docs = inv.n_terms, inv.n_docs
     all_terms = np.arange(n_terms) if terms is None else np.asarray(terms)
+    # terms without postings keep τ = inf: never fires, exhaustive scans
+    # treat them as no match
     tau = np.full(n_terms, np.inf, dtype=np.float32)
     backup: list[np.ndarray] = []
 
-    logit_fn = jax.jit(membership.pair_logits)
-    for t in all_terms:
-        docs = inv.postings(int(t))
-        if len(docs) == 0:
-            tau[t] = np.inf  # never fires; exhaustive scans treat as no match
-            continue
-        logits = np.asarray(
-            logit_fn(params, jnp.full(len(docs), t, jnp.int32), jnp.asarray(docs))
-        )
-        if backup_quantile > 0.0 and len(docs) > 8:
-            q = float(np.quantile(logits, backup_quantile))
-            spill = docs[logits < q]
+    live = all_terms[inv.dfs[all_terms] > 0]
+    logits = _positive_logits(params, inv, live)
+    dfs = inv.dfs[live]
+    bounds = np.concatenate([[0], np.cumsum(dfs)])
+    if backup_quantile > 0.0:
+        for j, t in enumerate(live):
+            lg = logits[bounds[j] : bounds[j + 1]]
+            if len(lg) <= 8:
+                tau[t] = lg.min()
+                continue
+            q = float(np.quantile(lg, backup_quantile))
+            spill = inv.postings(int(t))[lg < q]
             if len(spill):
                 backup.append(t * np.int64(n_docs) + spill.astype(np.int64))
             tau[t] = q
-        else:
-            tau[t] = float(logits.min())
+    elif len(live):
+        tau[live] = np.minimum.reduceat(logits, bounds[:-1])
     finite = np.isfinite(tau)
     tau[finite] -= NUMERIC_MARGIN * (1.0 + np.abs(tau[finite]))
     keys = np.sort(np.concatenate(backup)) if backup else np.zeros(0, np.int64)

@@ -72,7 +72,10 @@ def term_doc_logits(params: Any, terms: jax.Array, doc_tile: jax.Array | None = 
             axis=-1,
         )
         return nn.mlp(params["mlp"], h, act=jax.nn.gelu)[..., 0] + params["bias"]
-    return te @ dt.T + params["bias"]
+    # full float32 on the MXU: the zero-FN thresholds were fitted on exact
+    # float32 pair logits, and the TPU's default one-pass bf16 matmul would
+    # drift far past their margin
+    return jnp.matmul(te, dt.T, precision=jax.lax.Precision.HIGHEST) + params["bias"]
 
 
 def membership_loss(params: Any, batch: dict[str, jax.Array]) -> jax.Array:

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 W_BLK = 1024  # u32 words per tile = 32k blocks per grid step
 
 
@@ -50,7 +52,7 @@ def bitset_and_popcount(
     bitmaps: jax.Array,  # (Q, T, W) uint32, W % W_BLK == 0
     valid: jax.Array,  # (Q, T) int32 (bool as int for SMEM-friendliness)
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     q, t, w = bitmaps.shape
     assert w % W_BLK == 0, w
@@ -70,5 +72,5 @@ def bitset_and_popcount(
             jax.ShapeDtypeStruct((q, w), jnp.uint32),
             jax.ShapeDtypeStruct((q,), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(bitmaps, valid.astype(jnp.int32))

@@ -12,7 +12,7 @@ def query_block_intersect(
     bitmaps: jax.Array,  # (n_terms, W) uint32 — per-term block bitmaps
     queries: jax.Array,  # (Q, T) int32 padded with -1
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Returns ((Q, W) AND bitmap, (Q,) popcount of surviving blocks)."""
     w = bitmaps.shape[1]

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 B_BLK = 8  # candidate rows per grid step
 
 
@@ -38,7 +40,7 @@ def score_batch(
     impacts: jax.Array,  # (P, T) int32
     scale: jax.Array,  # (1, 1) float32 dequantization scale
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Score P candidate windows -> (int scores (P,1) i32, float (P,1) f32)."""
     P, T = impacts.shape
@@ -57,6 +59,6 @@ def score_batch(
             jax.ShapeDtypeStruct((P + pad, 1), jnp.int32),
             jax.ShapeDtypeStruct((P + pad, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(impacts, scale)
     return ints[:P], floats[:P]

@@ -23,7 +23,7 @@ def _bucket(n: int, quantum: int) -> int:
 
 
 def score_candidates(
-    impacts: np.ndarray, scale: float, *, interpret: bool = True
+    impacts: np.ndarray, scale: float, *, interpret: bool | None = None
 ) -> tuple[np.ndarray, np.ndarray]:
     """Score a (P, T) quantized-impact window on the Pallas kernel.
 

@@ -29,8 +29,8 @@ Tail lanes come in two flavours:
     window whose segment line reproduces the known doc id, with the payload
     words still unpacked in-register at the found rank.
 
-Axes are padded to power-of-two buckets (rows to the kernel block,
-candidates to 128·2^k, windows to 2^k) so jax.jit compiles a handful of
+Axes are padded to power-of-two buckets (rows to 8·2^k, candidates to
+128·2^k, windows to 2^k) so jax.jit compiles a handful of
 shapes — the same recompile-convoy discipline as the boolean path, which
 ``Session.warm()`` pre-triggers.  Candidate counts are heavy-tailed, so rows
 are *grouped* by candidate bucket, one dispatch per populated bucket: a
@@ -58,13 +58,14 @@ import numpy as np
 
 from repro.kernels.fused_query.dense import DENSE_MAX_K
 
-from repro.kernels.fused_query.kernel import B_BLK, NEVER, fused_topk
+from repro.kernels.fused_query.kernel import NEVER, fused_topk
 from repro.kernels.fused_query.ref import fused_topk_ref
 from repro.obs import trace
 from repro.rank.score import TopKResult, select_topk
 from repro.rank.topk import _EMPTY, _exhaustive, _kth_partial, _merge_add
 
 _CANDQ = 128  # candidate-axis bucket quantum
+_ROWQ = 8  # query-row bucket quantum
 W_CAP = 32  # widest ε-window shipped to the kernel; wider lanes resolve on host
 
 
@@ -261,7 +262,7 @@ def fused_topk_batch(
     exhaustive_cutoff: int = 2048,
     stats=None,
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Answer [(terms, k, required, floor), ...] with fused dispatches.
 
@@ -397,7 +398,7 @@ def _dispatch_group(src, pend, C, pbits, stats, results, *, use_kernel, interpre
     """One candidate-bucket group -> one fused kernel dispatch."""
     T = max(len(p.tail) for _, p in pend)
     K = min(max(p.k for _, p in pend), C)
-    Qb = _bucket(len(pend), B_BLK)
+    Qb = _bucket(len(pend), _ROWQ)
 
     lanes = []  # (row, slot, C_i, lane data) from the host window builder
     Wmax, stream_bytes = 1, 0

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 B_BLK = 8  # probes per grid step
 
 
@@ -48,7 +50,7 @@ def probe_batch(
     cands: jax.Array,  # (P, 1) int32
     corr: jax.Array,  # (P, W) int32
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Probe P windows -> (found (P,1) int32, lt (P,1) int32)."""
     P, W = corr.shape
@@ -68,6 +70,6 @@ def probe_batch(
             jax.ShapeDtypeStruct((P + pad, 1), jnp.int32),
             jax.ShapeDtypeStruct((P + pad, 1), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*scalars, corr)
     return found[:P], lt[:P]

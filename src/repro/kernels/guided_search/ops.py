@@ -41,7 +41,7 @@ def _bucket(n: int, quantum: int) -> int:
 
 
 def probe_windows(
-    tm, cands: np.ndarray, *, interpret: bool = True
+    tm, cands: np.ndarray, *, interpret: bool | None = None
 ) -> tuple[np.ndarray, np.ndarray, int]:
     """Batched guided probes of one term -> (found bool, rank int64, bytes).
 

@@ -25,7 +25,7 @@ def score_terms_bitmask(
     terms: jax.Array,  # (Q,) int32 term ids
     tau: jax.Array,  # (n_terms,) thresholds
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """(Q,) term ids -> (Q, ceil(D/32)) packed membership bitmask."""
     te = jnp.take(params["term_embed"]["table"], terms, axis=0)

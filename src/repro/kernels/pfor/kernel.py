@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 from repro.kernels.pfor.ref import BLOCK, words_per_block
 
 B_BLK = 64  # blocks decoded per grid step
@@ -48,7 +50,7 @@ def unpack_blocks(
     words: jax.Array,  # (n_blocks, words_per_block(width)) uint32
     *,
     width: int,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Decode same-width PFor blocks -> (n_blocks, 128) uint32 values."""
     n, wpb = words.shape
@@ -64,6 +66,6 @@ def unpack_blocks(
         in_specs=[pl.BlockSpec((B_BLK, wpb), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((B_BLK, BLOCK), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n + pad, BLOCK), jnp.uint32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(words)
     return out[:n]

@@ -40,7 +40,7 @@ def parse_stream(words: np.ndarray, n: int):
     return {w: np.stack(c) for w, c in batches.items()}, layout
 
 
-def decode_stream(words: np.ndarray, n: int, *, interpret: bool = True) -> np.ndarray:
+def decode_stream(words: np.ndarray, n: int, *, interpret: bool | None = None) -> np.ndarray:
     """Full OptPFD decode via the Pallas kernel; returns doc ids (gaps summed)."""
     batches, layout = parse_stream(words, n)
     decoded = {

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 from repro.kernels.plm_decode.ref import SENTINEL
 
 B_BLK = 8  # lists decoded per grid step
@@ -51,7 +53,7 @@ def decode_batch(
     slopes: jax.Array,  # (B, S) float32
     corr: jax.Array,  # (B, R) int32
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Decode B padded lists -> (B, R) int32 doc ids."""
     B, S = starts.shape
@@ -69,6 +71,6 @@ def decode_batch(
         in_specs=[seg_spec, seg_spec, seg_spec, pl.BlockSpec((B_BLK, R), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((B_BLK, R), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B + pad, R), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(starts, bases, slopes, corr)
     return out[:B]

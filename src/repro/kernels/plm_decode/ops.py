@@ -19,7 +19,7 @@ _SENTINEL = int(SENTINEL)
 
 
 def decode_lists(
-    streams: list[np.ndarray], lens: list[int], *, interpret: bool = True
+    streams: list[np.ndarray], lens: list[int], *, interpret: bool | None = None
 ) -> list[np.ndarray]:
     """Batched exact decode of many plm/rmi streams -> list of int32 id arrays."""
     nonempty = [i for i, n in enumerate(lens) if n > 0]

@@ -151,6 +151,7 @@ def dryrun_cell(arch_id: str, shape_name: str, *, multi_pod: bool = False,
         "mesh": "2x16x16" if multi_pod else "16x16",
         "status": "ok",
         "kind": cell.kind,
+        "device_kind": mesh.devices.flat[0].device_kind,
         "n_devices": int(n_dev),
         "lower_s": round(t_lower, 1),
         "compile_s": round(t_compile, 1),

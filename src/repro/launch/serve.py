@@ -19,8 +19,9 @@ JSONL record per routed probe with its route decision and bytes touched.
 --replicas R additionally drives the same batch through the continuous-
 batching scheduler (serve/sched.Session.submit): R=0 serves inline on the
 facade's own shards, R>0 spawns R process replicas per shard over the
-persistent store; --deadline-ms bounds each request's queue wait (late
-requests come back as typed Rejected, never silently dropped).
+persistent store (refused on a TPU, which belongs to one process);
+--deadline-ms bounds each request's queue wait (late requests come back as
+typed Rejected, never silently dropped).
 
 With --replicas and --trace-out together the trace is *distributed*: worker
 replicas ship their span buffers back with every response and the launcher
@@ -50,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.compile_cache import configure_compile_cache
 from repro.common.config import CorpusConfig, LearnedIndexConfig, OptimizerConfig
 from repro.core import fit_thresholds, init_membership, membership_loss
 from repro.data.corpus import synthesize_corpus
@@ -59,6 +61,12 @@ from repro.index.build import build_inverted_index
 from repro.obs import ProbeLog, Tracer
 from repro.serve import BooleanEngine, RankedConfig, ServeConfig
 from repro.train import init_train_state, make_train_step
+
+
+def build_collection(ccfg: CorpusConfig):
+    """Synthesize the seeded collection and invert it -> (corpus, inv)."""
+    corpus = synthesize_corpus(ccfg)
+    return corpus, build_inverted_index(corpus)
 
 
 def train_membership(corpus, inv, li_cfg: LearnedIndexConfig, steps=300, lr=0.05):
@@ -128,11 +136,14 @@ def main():
     args = ap.parse_args()
     if args.slo and args.replicas is None:
         args.replicas = 0  # the SLO report reads the scheduler's window
+    if args.replicas and jax.default_backend() == "tpu":
+        ap.error(f"--replicas {args.replicas}: process replicas are separate "
+                 "processes, and a TPU belongs to one process; use --replicas 0")
+    configure_compile_cache()
 
-    corpus = synthesize_corpus(
+    corpus, inv = build_collection(
         CorpusConfig(n_docs=args.docs, n_terms=args.terms, avg_doc_len=80)
     )
-    inv = build_inverted_index(corpus)
     li_cfg = LearnedIndexConfig(
         embed_dim=64, truncation_k=args.k, block_size=args.block_size
     )

@@ -1,7 +1,7 @@
 """Learned-postings subsystem: rank-model codecs for sorted doc-id lists.
 
 plm    — ε-bounded piecewise-linear model (PGM-style shrinking cone)
-rmi    — two-stage recursive model index (linear root + per-leaf LS in JAX)
+rmi    — two-stage recursive model index (linear root + per-leaf LS on the host)
 hybrid — per-term min-bits selection over learned + classical codecs
 
 All codecs are exactly lossless and report exact bit sizes; they register in

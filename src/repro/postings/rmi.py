@@ -3,8 +3,8 @@
 A recursive-model-index [Kraska et al. '18] specialized to the postings
 setting: stage 1 is a *linear root* over rank (ranks are uniform, so the
 root reduces to the exact affine bucketing ``leaf = i * L // n``); stage 2
-is one linear model per leaf, trained with closed-form least squares in JAX
-(segment-sum normal equations, no iterative optimizer).  Leaf models are
+is one linear model per leaf, trained with closed-form least squares on
+the host (segment-sum normal equations, no iterative optimizer).  Leaf models are
 anchored at the leaf's first doc id and the fitted intercept is rounded into
 that integer base, so the float32 regression only has to cover the
 within-leaf span — corrections stay narrow even for billion-scale universes
@@ -16,10 +16,6 @@ streams unchanged.
 """
 from __future__ import annotations
 
-from functools import partial
-
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.postings.plm import decode_stream, emit_stream, eval_segments, _stream_size_bits
@@ -38,22 +34,24 @@ def _leaf_starts(n: int, L: int) -> np.ndarray:
     return np.ceil(l * n / L).astype(np.int64)
 
 
-@partial(jax.jit, static_argnames=("L",))
-def _leaf_lstsq(x: jax.Array, y: jax.Array, leaf: jax.Array, L: int) -> tuple[jax.Array, jax.Array]:
+def _leaf_lstsq(
+    x: np.ndarray, y: np.ndarray, leaf: np.ndarray, L: int
+) -> tuple[np.ndarray, np.ndarray]:
     """Per-leaf 1D least squares via segment-sum normal equations.
 
-    x, y are leaf-centered (rank - leaf_start, doc_id - leaf_base) so float32
-    precision covers the within-leaf span only.  Returns (slopes, iceps).
+    x, y are leaf-centered (rank - leaf_start, doc_id - leaf_base) integers;
+    the sums run in float64 on the host, so the fit (and the stored stream)
+    is the same bytes on every machine.  Returns (slopes, iceps) float64.
     """
-    ones = jnp.ones_like(x)
-    cnt = jax.ops.segment_sum(ones, leaf, num_segments=L)
-    sx = jax.ops.segment_sum(x, leaf, num_segments=L)
-    sy = jax.ops.segment_sum(y, leaf, num_segments=L)
-    sxx = jax.ops.segment_sum(x * x, leaf, num_segments=L)
-    sxy = jax.ops.segment_sum(x * y, leaf, num_segments=L)
+    cnt = np.bincount(leaf, minlength=L).astype(np.float64)
+    sx = np.bincount(leaf, weights=x, minlength=L)
+    sy = np.bincount(leaf, weights=y, minlength=L)
+    sxx = np.bincount(leaf, weights=x * x, minlength=L)
+    sxy = np.bincount(leaf, weights=x * y, minlength=L)
     denom = cnt * sxx - sx * sx
-    slope = jnp.where(denom > 0, (cnt * sxy - sx * sy) / jnp.where(denom > 0, denom, 1.0), 0.0)
-    icep = jnp.where(cnt > 0, (sy - slope * sx) / jnp.where(cnt > 0, cnt, 1.0), 0.0)
+    ok = denom > 0
+    slope = np.where(ok, (cnt * sxy - sx * sy) / np.where(ok, denom, 1.0), 0.0)
+    icep = np.where(cnt > 0, (sy - slope * sx) / np.maximum(cnt, 1.0), 0.0)
     return slope, icep
 
 
@@ -75,23 +73,11 @@ def fit_rmi(
     anchors = ids64[starts]
     ranks = np.arange(n, dtype=np.int64)
     leaf = (ranks * L) // n
-    x = (ranks - starts[leaf]).astype(np.float32)
-    y = (ids64 - anchors[leaf]).astype(np.float32)
-    if L == 1:
-        # degenerate single-leaf model: same normal equations, no JAX
-        # dispatch overhead (short lists dominate a whole-index sweep)
-        denom = float(n * (x * x).sum() - x.sum() ** 2)
-        sl = (n * float((x * y).sum()) - float(x.sum()) * float(y.sum())) / denom if denom else 0.0
-        slopes = np.array([sl], np.float32)
-        iceps = np.array([(float(y.sum()) - sl * float(x.sum())) / n], np.float32)
-    else:
-        slopes, iceps = _leaf_lstsq(
-            jnp.asarray(x), jnp.asarray(y), jnp.asarray(leaf, jnp.int32), L
-        )
+    x = (ranks - starts[leaf]).astype(np.float64)
+    y = (ids64 - anchors[leaf]).astype(np.float64)
+    slopes, iceps = _leaf_lstsq(x, y, leaf, L)
     i32 = np.iinfo(np.int32)
-    bases = np.clip(
-        anchors + np.rint(np.asarray(iceps, np.float64)).astype(np.int64), i32.min, i32.max
-    )
+    bases = np.clip(anchors + np.rint(iceps).astype(np.int64), i32.min, i32.max)
     return starts, bases, np.asarray(slopes, np.float32)
 
 

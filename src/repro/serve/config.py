@@ -88,7 +88,9 @@ class SchedConfig:
     # (0 = dispatch whatever is queued the moment the scheduler is free)
     batch_window_us: int = 0
     # process replicas per shard; 0 = inline execution on the session's own
-    # dispatch thread (the engine's ShardEngines, serial fan-out)
+    # dispatch thread (the engine's ShardEngines, serial fan-out).  Replicas
+    # are processes, and a TPU belongs to one process: on a TPU backend only
+    # 0 is accepted (Session raises at construction)
     n_replicas: int = 0
     default_deadline_ms: float | None = None  # applied when a request has none
     tenant_quota: int | None = None  # max queued requests per tenant
@@ -109,9 +111,6 @@ class SchedConfig:
     # fresh worker re-compiles (or restores from the persistent compilation
     # cache) every executable the crashed one had warm
     warm_snapshot: bool = True
-    # directory for JAX's persistent compilation cache in workers (None =
-    # in-memory jit only); best-effort — unsupported builds ignore it
-    compile_cache_dir: str | None = None
 
 
 # legacy flat kwarg -> (sub-config attr, field on it)

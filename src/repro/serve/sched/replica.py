@@ -35,13 +35,13 @@ replacement re-pays each padded-shape compilation on first contact.  A
 ``ProcessReplica`` therefore keeps a small *warm log* — one sanitized
 (trace-context-stripped) representative message per distinct dispatch shape
 — and replays it into every freshly spawned process right after the ready
-handshake, before the replica serves its next request.  Paired with the
-persistent XLA compilation cache (sched/worker.py points
-``jax_compilation_cache_dir`` at the shard-store), the replay re-traces
-against on-disk executables instead of recompiling, so a respawned worker
-is serving-warm and bit-identical from its first real dispatch.  The log
-round-trips through ``Session.warm()``'s ``warm_snapshot.json`` so even a
-brand-new session restores the previous run's shape coverage.
+handshake, before the replica serves its next request.  Paired with a
+persistent XLA compilation cache (``JAX_COMPILATION_CACHE_DIR``, which
+workers inherit), the replay re-traces against on-disk executables instead
+of recompiling, so a respawned worker is serving-warm and bit-identical from
+its first real dispatch.  The log round-trips through ``Session.warm()``'s
+``warm_snapshot.json`` so even a brand-new session restores the previous
+run's shape coverage.
 """
 from __future__ import annotations
 

@@ -114,7 +114,7 @@ class Session:
         self._merge_us = self.metrics.histogram("sched.merge_us")
         self.slo = self.cfg.obs.slo if self.cfg.obs.slo is not None else SLOMonitor()
         self._trace_seq = itertools.count(1)  # trace ids for worker IPC
-        self._store_dir = store_dir  # warm-snapshot + compile-cache home
+        self._store_dir = store_dir  # warm-snapshot home
         self._groups = (
             replica_groups
             if replica_groups is not None
@@ -152,6 +152,14 @@ class Session:
                 )
                 for sh in eng.shards
             ]
+        import jax
+
+        if jax.default_backend() == "tpu":
+            raise RuntimeError(
+                f"sched.n_replicas={sc.n_replicas}: process replicas are separate "
+                "processes, and a TPU belongs to the one process that opened it "
+                "(this one) — serve inline with n_replicas=0 on the chip"
+            )
         if store_dir is None:
             raise ValueError(
                 "process replicas (sched.n_replicas > 0) rebuild engines from "
@@ -164,11 +172,6 @@ class Session:
         lb_tau = np.asarray(lb.tau)
         lb_backup = np.asarray(lb.backup_keys)
         global_dfs = np.asarray(eng._global_dfs)
-        # one shared persistent-compile-cache home per store: every worker of
-        # every (re)spawn deserializes executables the first run compiled
-        compile_cache_dir = sc.compile_cache_dir
-        if compile_cache_dir is None and sc.warm_snapshot:
-            compile_cache_dir = os.path.join(store_dir, "xla-compile-cache")
         snapshot = self._load_warm_snapshot(store_dir) if sc.warm_snapshot else None
         groups = []
         for idx, ((lo, hi), sh) in enumerate(zip(eng._ranges, eng._shards)):
@@ -186,7 +189,6 @@ class Session:
                 "li_cfg": eng.li_cfg,
                 "cfg_kwargs": eng.cfg.worker_spec(),
                 "global_dfs": global_dfs,
-                "compile_cache_dir": compile_cache_dir,
             }
             replicas = [
                 ProcessReplica(
@@ -274,7 +276,11 @@ class Session:
             if b >= self.sched_cfg.max_batch:
                 break
             b = min(2 * b, self.sched_cfg.max_batch)
-        if self.cfg.ranked.enabled and self.cfg.ranked.fused_kernel:
+        if (
+            self.cfg.ranked.enabled
+            and self.cfg.ranked.fused_kernel
+            and all(sh.can_rank for sh in self.engine.shards)
+        ):
             self._warm_fused(replicas, t)
         if self.sched_cfg.warm_snapshot and self.sched_cfg.n_replicas > 0:
             self.save_warm_snapshot()
@@ -285,8 +291,9 @@ class Session:
         The fused dispatch jit-specializes on its padded (rows, terms,
         candidates, window) bucket; driving the power-of-two row buckets with
         a real term keeps that compilation out of the serving path, same as
-        the boolean warm above.  Best-effort: a store without payload
-        streams can't rank, so failures leave the replica cold, not broken.
+        the boolean warm above.  A failure here (a kernel that does not
+        compile, a worker that died) propagates: it would otherwise surface
+        in the first served request.
         """
         # several dense terms at k=1: the threshold rises after the first
         # essential decode, leaving the rest as a probe tail for the kernel
@@ -298,11 +305,8 @@ class Session:
             futs = [
                 self._fan.submit(r.call, ("topk", [item] * b)) for r in replicas
             ]
-            try:
-                for f in futs:
-                    f.result()
-            except Exception:
-                return
+            for f in futs:
+                f.result()
             if b >= self.sched_cfg.max_batch:
                 return
             b = min(2 * b, self.sched_cfg.max_batch)

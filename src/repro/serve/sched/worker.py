@@ -25,12 +25,11 @@ Protocol (request/response over one ``multiprocessing.Pipe``):
   ("stop",)                       clean shutdown
   ("err", traceback_str)          any handler failure (worker stays alive)
 
-When the spec carries ``compile_cache_dir`` the worker points JAX's
-persistent compilation cache there before building its engine (best-effort
-— an old jax without the knobs just stays in-memory).  Every worker of
-every (re)spawn shares that directory, so the warm-log replay a fresh
-process receives (sched/replica.py) re-traces against executables already
-on disk instead of re-invoking XLA.
+Workers inherit the parent's environment, so where
+``JAX_COMPILATION_CACHE_DIR`` is set every worker of every (re)spawn shares
+that persistent compilation cache, and the warm-log replay a fresh process
+receives (sched/replica.py) re-traces against executables already on disk
+instead of re-invoking XLA.
 
 ``ctx`` is an optional ``repro.obs.TraceContext``: when present the reply
 grows a third element, ``("ok", payload, {"spans": [...], "probes": [...]})``
@@ -118,32 +117,6 @@ def cache_report(shard) -> dict:
     }
 
 
-def _configure_compile_cache(cache_dir: str | None) -> None:
-    """Point JAX's persistent compilation cache at the shard-store (best
-    effort): respawned workers then deserialize executables instead of
-    recompiling them during the warm-log replay."""
-    if not cache_dir:
-        return
-    try:
-        import jax
-
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        for knob, val in (
-            # CPU-backend kernels compile fast/small; without zeroing the
-            # thresholds the cache would skip exactly the executables the
-            # respawn replay wants back
-            ("jax_persistent_cache_min_compile_time_secs", 0.0),
-            ("jax_persistent_cache_min_entry_size_bytes", 0),
-        ):
-            try:
-                jax.config.update(knob, val)
-            except Exception:
-                pass
-    except Exception:
-        pass
-
-
 def _build_shard(spec: dict):
     """Reconstruct the spec'd ShardEngine from the persistent shard-store."""
     from repro.core.learned_bloom import LearnedBloom
@@ -175,7 +148,6 @@ def worker_main(conn, spec: dict) -> None:
     from repro.obs.trace import Tracer
 
     try:
-        _configure_compile_cache(spec.get("compile_cache_dir"))
         shard, cfg = _build_shard(spec)
         # in-memory probe sink, installed before the engine's first probe
         # (GuidedPostings captures the handle lazily); drained per request

@@ -233,6 +233,16 @@ class ShardEngine:
             self._ranked = _RankedSource(self)
         return self._ranked
 
+    @property
+    def can_rank(self) -> bool:
+        """Whether this shard can serve the ranked tier: its store carries
+        payload streams, or can quantize them from the index's tfs."""
+        if self.cfg.postings_store != "hybrid":
+            return False
+        if self._tier2 is not None and self._tier2.has_payloads:
+            return True
+        return self._impact_model is not None and self.inv.tfs is not None
+
     def query_topk_local(
         self,
         terms,

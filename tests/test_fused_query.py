@@ -248,6 +248,39 @@ def test_kernel_bit_identical_to_reference(system, engines):
     _check(kern, ref, "Pallas kernel must match the numpy reference bit-for-bit")
 
 
+@pytest.mark.parametrize("k", [7, 40])
+def test_kernel_block_merge_matches_reference(k):
+    """A candidate axis of two C_BLK blocks: per-block heaps merged on the
+    device equal the reference's single heap, score ties included."""
+    from repro.kernels.fused_query.kernel import C_BLK, NEVER, fused_topk
+    from repro.kernels.fused_query.ref import fused_topk_ref
+
+    rng = np.random.default_rng(k)
+    Q, T, C, W, pbits = 3, 2, 2 * C_BLK, 2, 4
+    cand = np.full((Q, C), NEVER, np.int32)
+    for r in range(Q):
+        n = C - 100 * r  # ragged rows: the tail stays NEVER-padded
+        cand[r, :n] = np.sort(rng.choice(1 << 20, n, replace=False))
+    # resolved lanes: the segment line reproduces the candidate exactly
+    rlo = rng.integers(0, 1 << 12, (Q, T, C)).astype(np.int32)
+    wlen = rng.integers(0, W + 1, (Q, T, C)).astype(np.int32)
+    arrays = (
+        np.zeros((Q, T), np.uint32), np.zeros((Q, T), np.int32), rlo, wlen,
+        rlo.copy(), np.broadcast_to(cand[:, None, :], (Q, T, C)).copy(),
+        np.zeros((Q, T, C), np.float32),
+        np.zeros((Q, T, C, W), np.uint32), np.zeros((Q, T, C, W), np.uint32),
+        rng.integers(0, 1 << 32, (Q, T, C, W), dtype=np.uint32),
+        rng.integers(0, 1 << 32, (Q, T, C, W), dtype=np.uint32),
+        cand, rng.integers(0, 3, (Q, C)).astype(np.int32),
+        np.array([[0], [5], [12]], np.int32),
+    )
+    ids, scores = fused_topk(*(jax.numpy.asarray(a) for a in arrays), k=k, pbits=pbits)
+    want_ids, want_scores = fused_topk_ref(*arrays, k=k, pbits=pbits)
+    assert (want_scores[:, 0] > 0).all()
+    np.testing.assert_array_equal(np.asarray(scores), want_scores)
+    np.testing.assert_array_equal(np.asarray(ids), want_ids)
+
+
 # -------------------------------------------------------- serve-path wiring
 def test_empty_run_shards_short_circuit(system, engines, monkeypatch):
     """A shard whose every run mask is empty is skipped before heap setup."""

@@ -109,3 +109,74 @@ def test_block_lists_bits(inv):
         for b in range(n_blocks):
             bit = bool((bm[t, b // 32] >> np.uint32(b % 32)) & 1)
             assert bit == (b in blocks)
+
+
+# --------------------------------------------- store build: compiles, bytes
+def _backend_compiles(fn) -> int:
+    """Backend compilations that ``fn()`` triggered."""
+    import jax
+
+    seen = []
+
+    def listener(name, _secs, **_kw):
+        if name == "/jax/core/compile/backend_compile_duration":
+            seen.append(name)
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        fn()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    return len(seen)
+
+
+def test_store_build_compiles_independent_of_list_lengths():
+    """Thresholds + hybrid store over two collections with different list
+    lengths: the first compiles at most the one logit program, the second
+    nothing, however many distinct lengths the RMI candidates see."""
+    import jax
+
+    from repro.common.config import LearnedIndexConfig
+    from repro.core import fit_thresholds, init_membership
+    from repro.postings import HybridPostings
+    from repro.postings.hybrid import RMI_MIN_N
+
+    li_cfg = LearnedIndexConfig(embed_dim=16)
+    params, _ = init_membership(jax.random.key(0), li_cfg, 400, 3000)
+    counts, lengths = [], set()
+    for seed in (1, 2):
+        inv = build_inverted_index(synthesize_corpus(
+            CorpusConfig(n_docs=3000, n_terms=400, avg_doc_len=60, seed=seed)
+        ))
+        lengths |= {int(n) for n in inv.dfs if n >= RMI_MIN_N}
+        counts.append(_backend_compiles(
+            lambda: (fit_thresholds(params, inv), HybridPostings.from_index(inv))
+        ))
+    assert len(lengths) > 50
+    assert counts[0] <= 1 and counts[1] == 0, counts
+
+
+def test_store_built_twice_is_byte_identical(tmp_path):
+    """The persisted store is a pure function of the seed: two independent
+    builds write the same files, byte for byte."""
+    import jax
+
+    from repro.common.config import LearnedIndexConfig
+    from repro.core import fit_thresholds, init_membership
+    from repro.serve import BooleanEngine, ServeConfig
+
+    li_cfg = LearnedIndexConfig(embed_dim=16, truncation_k=16, block_size=64)
+    for out in ("a", "b"):
+        corpus = synthesize_corpus(
+            CorpusConfig(n_docs=1500, n_terms=300, avg_doc_len=60, seed=4)
+        )
+        inv = build_inverted_index(corpus)
+        params, _ = init_membership(jax.random.key(0), li_cfg, 300, 1500)
+        lb = fit_thresholds(params, inv)
+        BooleanEngine(lb, inv, li_cfg, ServeConfig(n_shards=2)).save(str(tmp_path / out))
+    files = sorted(p.relative_to(tmp_path / "a") for p in (tmp_path / "a").rglob("*") if p.is_file())
+    assert files and files == sorted(
+        p.relative_to(tmp_path / "b") for p in (tmp_path / "b").rglob("*") if p.is_file()
+    )
+    for f in files:
+        assert (tmp_path / "a" / f).read_bytes() == (tmp_path / "b" / f).read_bytes(), f

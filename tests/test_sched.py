@@ -313,6 +313,42 @@ def test_process_worker_crash_retry_then_typed_failure(system, tmp_path):
         assert snap["worker_retries"] == 1 and snap["worker_failures"] == 1
 
 
+def test_process_replicas_refused_on_tpu(system, tmp_path, monkeypatch):
+    """A TPU belongs to one process: asking for process replicas there fails
+    at construction, before anything is saved or spawned."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng = _engine(system, n_shards=1, sched=dict(n_replicas=1))
+    with pytest.raises(RuntimeError, match="one process"):
+        Session(eng, store_dir=str(tmp_path))
+    assert not (tmp_path / "shards.json").exists()
+
+
+def test_launcher_refuses_replicas_on_tpu(monkeypatch):
+    """launch/serve.py --replicas N>0 stops at argument parsing on a TPU."""
+    from repro.launch import serve as launcher
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr("sys.argv", ["serve", "--replicas", "1"])
+    with pytest.raises(SystemExit) as ei:
+        launcher.main()
+    assert ei.value.code == 2
+
+
+def test_warm_propagates_a_failing_fused_dispatch(system, monkeypatch):
+    """A ranked warm-up failure surfaces from warm(), not from the first
+    served request."""
+    import repro.kernels.fused_query.ops as fused_ops
+
+    def boom(*a, **kw):
+        raise RuntimeError("fused kernel failed to compile")
+
+    monkeypatch.setattr(fused_ops, "fused_topk_batch", boom)
+    eng = _engine(system, n_shards=1, ranked=dict(fused_kernel=True))
+    with Session(eng) as s:
+        with pytest.raises(RuntimeError, match="failed to compile"):
+            s.warm()
+
+
 # ---------------------------------------------------------- short-circuits
 def test_all_pad_and_k0_short_circuit_without_dispatch(system):
     eng = _engine(system, n_shards=1)
@@ -595,7 +631,9 @@ def test_warm_snapshot_respawn_bit_identical_and_re_jit_free(system, tmp_path):
             assert np.array_equal(a.ids, b.ids)
             assert np.array_equal(a.scores, b.scores)
     assert (tmp_path / "warm_snapshot.json").exists()
-    assert (tmp_path / "xla-compile-cache").is_dir()
+    # the compile cache is placed from outside (JAX_COMPILATION_CACHE_DIR),
+    # never inside the shard-store
+    assert not (tmp_path / "xla-compile-cache").exists()
     # a brand-new session over the same store preloads the snapshot, so its
     # first spawn replays the previous run's whole shape coverage
     eng2 = _engine(

@@ -9,8 +9,7 @@ from repro.common.sharding import DEFAULT_RULES, abstract_mesh, resolve_axis, sp
 @pytest.fixture(scope="module")
 def mesh():
     # single-device CI mesh still exercises the resolution logic with
-    # symbolic axis names via an abstract mesh; abstract_mesh papers over
-    # the AbstractMesh signature change across JAX releases
+    # symbolic axis names via an abstract mesh
     return abstract_mesh((16, 16), ("data", "model"))
 
 

@@ -124,3 +124,44 @@ def test_checkpoint_resume_training(tmp_path):
     assert int(rst.step) == 20
     for a, b in zip(jax.tree.leaves(ref_p), jax.tree.leaves(rp)):
         assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+# ------------------------------------------------------------ compile cache
+_CACHE_PROBE = """
+import jax, jax.numpy as jnp
+from repro.common.compile_cache import configure_compile_cache
+path = configure_compile_cache()
+if {compile}:
+    jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()
+print(path)
+print(jax.config.jax_compilation_cache_dir)
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "default"])
+def test_compile_cache_placement(tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR set: the cache is written there and nothing
+    overrides it; unset: the fixed in-checkout path, never a temp dir."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    from repro.common.compile_cache import DEFAULT_DIR, ENV_VAR
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(Path(__file__).resolve().parents[1] / "src"), env.get("PYTHONPATH", "")]
+    )
+    env.pop(ENV_VAR, None)
+    want = DEFAULT_DIR
+    if from_env:
+        want = tmp_path / "cache"
+        env[ENV_VAR] = str(want)
+    out = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE.format(compile=from_env)],
+        env=env, capture_output=True, text=True, timeout=120, check=True,
+    ).stdout.split()
+    assert out == [str(want), str(want)]
+    if from_env:
+        assert any(want.iterdir()), "the compile was not cached where the env says"
