@@ -1,8 +1,9 @@
 """Observability for the serving stack: tracing, metrics, probe logging.
 
-  trace.py     nestable span tracer, Chrome-trace/Perfetto JSON export,
-               ambient activation so deep layers need no tracer plumbing;
-               TraceContext + span wire format for process-replica IPC
+  trace.py     causal span tracer (ids, parents, depths), Chrome-trace/
+               Perfetto JSON export, ambient activation so deep layers need
+               no tracer plumbing; TraceContext + span wire format for
+               process-replica IPC
   collate.py   replica clock-offset estimation (min-RTT ping) and merging
                shipped worker spans onto the host timeline in pid lanes
   metrics.py   counters / gauges / fixed-bucket histograms behind one
@@ -25,8 +26,10 @@ from repro.obs.metrics import Counter, Gauge, Histogram, Registry
 from repro.obs.probelog import ProbeLog, ProbeRecord
 from repro.obs.slo import SLOMonitor
 from repro.obs.trace import (
+    ASYNC_TID,
     NULL_SPAN,
     Span,
+    SpanRef,
     TraceContext,
     Tracer,
     activate,
@@ -35,6 +38,7 @@ from repro.obs.trace import (
 )
 
 __all__ = [
+    "ASYNC_TID",
     "Counter",
     "Gauge",
     "Histogram",
@@ -44,6 +48,7 @@ __all__ = [
     "Registry",
     "SLOMonitor",
     "Span",
+    "SpanRef",
     "TraceContext",
     "Tracer",
     "activate",

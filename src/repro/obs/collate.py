@@ -17,7 +17,10 @@ process.  This module owns the two halves of stitching them together:
     (Tracer.drain_wire); subtracting the offset and the host tracer's epoch
     lands them on the host timeline in host microseconds.  Each span keeps
     the worker's os pid, so the Chrome trace renders every replica as its
-    own named process lane next to the host's lane 0.
+    own named process lane next to the host's lane 0.  Span ids are the
+    worker tracer's own: keyed by (pid, id), they are replaced by ids the
+    host tracer draws, and the worker's root keeps the parent the
+    ``TraceContext`` named, the host span that dispatched it.
 
 ``nesting_violations`` is the invariant checker the tests (and anyone
 debugging a skewed trace) lean on: within one (pid, tid) lane, complete
@@ -29,7 +32,7 @@ from __future__ import annotations
 import time
 from typing import Callable, Iterable
 
-from repro.obs.trace import Span, Tracer
+from repro.obs.trace import ASYNC_TID, Span, Tracer
 
 CLOCK_SYNC_PINGS = 7  # round trips per sync; min-RTT sample wins
 
@@ -58,7 +61,8 @@ def estimate_clock_offset(
 
 
 def span_from_wire(d: dict, *, offset_ns: int, epoch_ns: int, pid: int) -> Span:
-    """One wire dict (Tracer.drain_wire) -> a Span on the host timeline."""
+    """One wire dict (Tracer.drain_wire) -> a Span on the host timeline,
+    with the worker's own id and parent."""
     return Span(
         name=d["name"],
         ts_us=(d["ts_ns"] - offset_ns - epoch_ns) / 1e3,
@@ -67,6 +71,8 @@ def span_from_wire(d: dict, *, offset_ns: int, epoch_ns: int, pid: int) -> Span:
         depth=d["depth"],
         attrs=dict(d.get("attrs") or {}),
         pid=pid,
+        id=d["id"],
+        parent=d["parent"],
     )
 
 
@@ -82,17 +88,27 @@ def ingest_worker_spans(
 
     ``offset_ns`` comes from ``estimate_clock_offset`` against that replica;
     ``pid`` keys the replica's Chrome-trace lane and ``label`` names it.
+    ``wire_spans`` is one reply's buffer: the spans of one dispatch, all
+    under one worker root, the least deep of them, whose parent is already
+    a host span; every other parent is a worker id, mapped with the ids.
     Returns the number of spans ingested.
     """
     if label is not None:
         tracer.set_process_name(pid, label)
-    n = 0
-    for d in wire_spans:
-        tracer.add_span(
-            span_from_wire(d, offset_ns=offset_ns, epoch_ns=tracer.epoch_ns, pid=pid)
-        )
-        n += 1
-    return n
+    spans = [
+        span_from_wire(d, offset_ns=offset_ns, epoch_ns=tracer.epoch_ns, pid=pid)
+        for d in wire_spans
+    ]
+    if not spans:
+        return 0
+    ids = {(pid, s.id): tracer.next_id() for s in spans}
+    top = min(s.depth for s in spans)
+    for s in spans:
+        if s.depth != top:
+            s.parent = ids[(pid, s.parent)]
+        s.id = ids[(pid, s.id)]
+        tracer.add_span(s)
+    return len(spans)
 
 
 def nesting_violations(spans: Iterable[Span], slack_us: float = 0.0) -> list[str]:
@@ -100,12 +116,15 @@ def nesting_violations(spans: Iterable[Span], slack_us: float = 0.0) -> list[str
 
     Within one (pid, tid) lane, any two spans must either nest (one interval
     contains the other) or be disjoint; a partial overlap beyond
-    ``slack_us`` is reported.  Returns human-readable violation strings
-    (empty = the collated timeline is consistent).
+    ``slack_us`` is reported.  Spans on ``ASYNC_TID`` belong to no thread
+    and may overlap (two requests in flight); they are left out.  Returns
+    human-readable violation strings (empty = the collated timeline is
+    consistent).
     """
     lanes: dict[tuple[int, int], list[Span]] = {}
     for s in spans:
-        lanes.setdefault((s.pid, s.tid), []).append(s)
+        if s.tid != ASYNC_TID:
+            lanes.setdefault((s.pid, s.tid), []).append(s)
     bad: list[str] = []
     for (pid, tid), lane in lanes.items():
         # sort by start, longest first, so containment shows up as a stack

@@ -15,6 +15,15 @@ spans through the module-level ``span()``.  Activation is thread-local; the
 facade re-activates inside worker threads when the probe phase fans out, so
 spans carry the worker's tid and the trace shows real parallelism.
 
+Spans are *causal*: each recorded span has an ``id`` (unique per tracer)
+and the id of the span that caused it, its ``parent`` (0 for a root), and
+its ``depth`` is the parent's depth + 1.  Within a thread the parent is the
+innermost open span; a span opened on another thread hangs under the span
+``activate(tracer, parent=...)`` installed there, so the work a dispatch
+fans out to pool threads nests under the dispatch.  Intervals that cross
+threads (a request's life, its queue wait) are recorded after the fact on
+``ASYNC_TID`` under an id reserved with ``Tracer.root()`` when they began.
+
 Span timestamps are ``perf_counter_ns`` relative to the tracer's epoch,
 reported in microseconds (the Chrome trace unit).  Attributes are free-form
 key/values rendered into the event's ``args``; callers attach measured
@@ -27,14 +36,19 @@ own timeline with the replica's estimated clock offset (obs/collate.py).
 ``Span.pid`` keeps each process in its own Chrome-trace lane;
 ``set_process_name`` labels the lanes.  The ``TraceContext`` carried with
 each IPC request tells the worker whether to trace at all, so the trace-off
-path still costs nothing on the wire.
+path still costs nothing on the wire, and names the dispatching span as the
+parent of the worker's root span.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import threading
 import time
 from dataclasses import dataclass, field
+from typing import NamedTuple
+
+ASYNC_TID = -1  # lane of spans recorded after the fact across threads (no thread)
 
 
 @dataclass
@@ -44,10 +58,22 @@ class Span:
     name: str
     ts_us: float
     dur_us: float
-    tid: int
-    depth: int  # nesting level inside its thread (0 = top-level)
+    tid: int  # thread ident, or ASYNC_TID for a span that crosses threads
+    depth: int  # causal depth: the parent's depth + 1 (0 = a root)
     attrs: dict = field(default_factory=dict)
     pid: int = 0  # 0 = the tracer's own process; workers keep their os pid
+    id: int = 0  # unique per tracer
+    parent: int = 0  # id of the span that caused this one (0 = a root)
+
+
+class SpanRef(NamedTuple):
+    """A parent for spans opened elsewhere: an id and that span's depth.
+
+    Open span handles and ``NULL_SPAN`` (id 0, depth -1: children are
+    roots) serve as parents the same way."""
+
+    id: int
+    depth: int
 
 
 @dataclass
@@ -55,11 +81,13 @@ class TraceContext:
     """Per-request observability contract carried through worker IPC.
 
     Pickles with the request message; the worker reads it to decide what to
-    ship back (span buffer, probe records) and tags its spans with
-    ``trace_id`` so one request renders end-to-end across pid lanes.
+    ship back (span buffer, probe records), and opens its root span under
+    the dispatching host span it names, so one request renders end to end
+    across pid lanes.
     """
 
-    trace_id: int = 0
+    parent: int = 0  # id of the dispatching span on the host
+    parent_depth: int = -1  # its depth (-1: none, the worker's root is a root)
     trace: bool = False  # ship finished spans back with the response
     probe: bool = False  # ship routed-probe records back with the response
 
@@ -68,6 +96,8 @@ class _NullSpan:
     """Shared no-op handle: the entire trace-off path."""
 
     __slots__ = ()
+    id = 0  # as a parent: spans under it are roots
+    depth = -1
 
     def __enter__(self) -> "_NullSpan":
         return self
@@ -98,36 +128,49 @@ def span(name: str, **attrs) -> "_SpanHandle | _NullSpan":
 
 
 class _Activation:
-    """Context manager installing a tracer as this thread's ambient one."""
+    """Context manager installing a tracer as this thread's ambient one,
+    and optionally a parent for the spans this thread opens under it."""
 
-    __slots__ = ("_tracer", "_prev")
+    __slots__ = ("_tracer", "_parent", "_prev")
 
-    def __init__(self, tracer: "Tracer | None"):
+    def __init__(self, tracer: "Tracer | None", parent=None):
         self._tracer = tracer
+        self._parent = parent
 
     def __enter__(self) -> "Tracer | None":
+        tracer = self._tracer
         self._prev = getattr(_ambient, "tracer", None)
-        if self._tracer is not None:
-            _ambient.tracer = self._tracer
-        return self._tracer
+        if tracer is not None:
+            _ambient.tracer = tracer
+            if self._parent is not None:
+                tracer._stack().append(self._parent)
+        return tracer
 
     def __exit__(self, *exc) -> bool:
-        if self._tracer is not None:
+        tracer = self._tracer
+        if tracer is not None:
+            if self._parent is not None:
+                tracer._stack().pop()
             _ambient.tracer = self._prev
         return False
 
 
-def activate(tracer: "Tracer | None") -> _Activation:
+def activate(tracer: "Tracer | None", parent=None) -> _Activation:
     """Install ``tracer`` for a with-block; ``activate(None)`` is a no-op
     (it leaves any outer activation in place, so a traced caller still sees
-    spans from an engine whose own config carries no tracer)."""
-    return _Activation(tracer)
+    spans from an engine whose own config carries no tracer).
+
+    ``parent`` (an open span handle, a ``SpanRef`` or ``NULL_SPAN``) becomes
+    the parent of the outermost spans opened in the block: a span that hands
+    work to another thread passes itself, and that work hangs under it."""
+    return _Activation(tracer, parent)
 
 
 class _SpanHandle:
-    """Live span: records a Span onto its tracer at ``__exit__``."""
+    """Live span: draws its id and parent at ``__enter__``, records a Span
+    onto its tracer at ``__exit__``."""
 
-    __slots__ = ("_tracer", "name", "attrs", "_t0")
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "id", "parent", "depth")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -140,23 +183,29 @@ class _SpanHandle:
         return self
 
     def __enter__(self) -> "_SpanHandle":
-        self._tracer._stack().append(self)
+        tracer = self._tracer
+        stack = tracer._stack()
+        up = stack[-1] if stack else NULL_SPAN
+        self.parent, self.depth = up.id, up.depth + 1
+        self.id = next(tracer._ids)
+        stack.append(self)
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter_ns()
         tracer = self._tracer
-        stack = tracer._stack()
-        stack.pop()
+        tracer._stack().pop()
         tracer._record(
             Span(
                 name=self.name,
                 ts_us=(self._t0 - tracer.epoch_ns) / 1e3,
                 dur_us=(t1 - self._t0) / 1e3,
                 tid=threading.get_ident(),
-                depth=len(stack),
+                depth=self.depth,
                 attrs=self.attrs,
+                id=self.id,
+                parent=self.parent,
             )
         )
         return False
@@ -169,6 +218,7 @@ class Tracer:
         self.name = name
         self.epoch_ns = time.perf_counter_ns()
         self.spans: list[Span] = []
+        self._ids = itertools.count(1)  # span ids; never restarted
         self._lock = threading.Lock()
         self._local = threading.local()
         self._process_names: dict[int, str] = {0: name}
@@ -180,13 +230,23 @@ class Tracer:
             stack = self._local.stack = []
         return stack
 
+    def next_id(self) -> int:
+        return next(self._ids)
+
+    def root(self) -> SpanRef:
+        """Reserve the id of a root span that is recorded later with
+        ``add_span`` (a request's span, written when the request ends), so
+        the spans it causes can name it as their parent meanwhile."""
+        return SpanRef(self.next_id(), 0)
+
     def _record(self, s: Span) -> None:
         with self._lock:
             self.spans.append(s)
 
     def add_span(self, s: Span) -> None:
         """Append an externally constructed span (collated worker spans,
-        retroactive queue-wait spans) onto this tracer's timeline."""
+        retroactive request and queue-wait spans) onto this tracer's
+        timeline; it brings its own id and parent."""
         with self._lock:
             self.spans.append(s)
 
@@ -202,6 +262,8 @@ class Tracer:
         return _Activation(self)
 
     def reset(self) -> None:
+        """Drop the recorded spans and restart the clock; ids keep counting,
+        so a span reserved before the reset stays unique after it."""
         with self._lock:
             self.spans.clear()
         self.epoch_ns = time.perf_counter_ns()
@@ -227,6 +289,8 @@ class Tracer:
                 "tid": s.tid,
                 "depth": s.depth,
                 "attrs": s.attrs,
+                "id": s.id,
+                "parent": s.parent,
             }
             for s in spans
         ]
@@ -235,17 +299,21 @@ class Tracer:
     def chrome_trace(self) -> dict:
         """The trace as a Chrome/Perfetto ``traceEvents`` document.
 
-        Every span becomes one complete ("X") event; nesting is implied by
-        (pid, tid, ts, dur) containment, which the viewers render as stacks.
-        Worker spans collated from process replicas keep their own pid, so
-        each replica renders as its own named process lane ("M" metadata
-        events carry the labels).
+        Every thread's span becomes one complete ("X") event; nesting is
+        implied by (pid, tid, ts, dur) containment, which the viewers render
+        as stacks.  A span on ``ASYNC_TID`` crosses threads and overlaps its
+        siblings, so it becomes an async begin/end ("b"/"e") pair keyed by
+        its id.  ``args`` carry each span's ``id`` and ``parent``.  Worker
+        spans collated from process replicas keep their own pid, so each
+        replica renders as its own named process lane ("M" metadata events
+        carry the labels).
         """
         with self._lock:
             spans = list(self.spans)
             names = dict(self._process_names)
-        events = [
-            {
+        events = []
+        for s in spans:
+            ev = {
                 "name": s.name,
                 "cat": "serve",
                 "ph": "X",
@@ -253,11 +321,15 @@ class Tracer:
                 "dur": s.dur_us,
                 "pid": s.pid,
                 "tid": s.tid,
-                "args": dict(s.attrs),
+                "args": dict(s.attrs, id=s.id, parent=s.parent),
             }
-            for s in spans
-        ]
-        n_spans = len(events)
+            if s.tid == ASYNC_TID:
+                del ev["dur"]
+                ev.update(ph="b", cat="request", id=s.id)
+                events += [ev, dict(ev, ph="e", ts=s.ts_us + s.dur_us, args={})]
+            else:
+                events.append(ev)
+        n_spans = len(spans)
         events += [
             {
                 "name": "process_name",

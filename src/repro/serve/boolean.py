@@ -252,9 +252,8 @@ class BooleanEngine:
         out: list[TopKResult] = []
         with trace.activate(self.cfg.obs.trace), \
                 trace.span("serve.topk_batch", queries=int(q.shape[0]), k=int(k)):
-            with trace.span("serve.plan"):
-                qplans = plan_ranked(q, self._global_dfs, mode=mode, required=required)
-                runs = [ranked_run_mask(qplans, sh.local_dfs) for sh in active]
+            qplans = plan_ranked(q, self._global_dfs, mode=mode, required=required)
+            runs = [ranked_run_mask(qplans, sh.local_dfs) for sh in active]
             # a shard whose run mask is all-empty contributes nothing to any
             # heap: drop it here instead of re-deriving floors against it
             live = [(sh, run) for sh, run in zip(active, runs) if run.any()]
@@ -352,9 +351,8 @@ class BooleanEngine:
                 trace.span("serve.batch", queries=int(q.shape[0]),
                            shards=len(active)):
             t0 = time.perf_counter_ns()
-            with trace.span("serve.plan"):
-                plan = plan_batch(q, self._global_dfs, active,
-                                  verified=self.cfg.verified)
+            plan = plan_batch(q, self._global_dfs, active,
+                              verified=self.cfg.verified)
             self._observe_us("plan_us", t0)
             t0 = time.perf_counter_ns()
             masks = []

@@ -16,6 +16,9 @@ planner turns a padded query batch into
     smallest local df in the query, and pins the term to 'guided' ε-window
     probes or 'decode' (full decompression through the shard's CostLRU).
 
+Both entry points, ``plan_batch`` and ``plan_ranked``, open one
+``serve.plan`` span per call.
+
 Executors (serve/shard.ShardEngine) honor the plan verbatim; routing hints
 never affect result exactness — both probe paths are exact — only which
 stream bytes the shard touches.  Unverified serving keeps only the padding
@@ -33,6 +36,8 @@ from dataclasses import dataclass
 from typing import Protocol, Sequence
 
 import numpy as np
+
+from repro.obs import trace
 
 
 class ShardLike(Protocol):
@@ -122,21 +127,22 @@ def plan_ranked(
         )
     dfs = np.asarray(global_dfs)
     out = []
-    for qi, row in enumerate(queries):
-        raw = sorted({int(t) for t in row if t >= 0})
-        if required is not None:
-            req_raw = {int(t) for t, r in zip(row, required[qi]) if t >= 0 and r}
-        else:
-            req_raw = set(raw) if mode == "and" else set()
-        terms = tuple(t for t in raw if int(dfs[t]) > 0)
-        dead = not terms or any(int(dfs[t]) == 0 for t in req_raw)
-        out.append(
-            RankedQueryPlan(
-                terms=terms,
-                required=tuple(sorted(req_raw & set(terms))),
-                dead=dead,
+    with trace.span("serve.plan", queries=len(queries)):
+        for qi, row in enumerate(queries):
+            raw = sorted({int(t) for t in row if t >= 0})
+            if required is not None:
+                req_raw = {int(t) for t, r in zip(row, required[qi]) if t >= 0 and r}
+            else:
+                req_raw = set(raw) if mode == "and" else set()
+            terms = tuple(t for t in raw if int(dfs[t]) > 0)
+            dead = not terms or any(int(dfs[t]) == 0 for t in req_raw)
+            out.append(
+                RankedQueryPlan(
+                    terms=terms,
+                    required=tuple(sorted(req_raw & set(terms))),
+                    dead=dead,
+                )
             )
-        )
     return out
 
 
@@ -167,27 +173,28 @@ def plan_batch(
 ) -> BatchPlan:
     """Full batch plan over the given executor shards (see module docstring)."""
     q = np.asarray(queries, dtype=np.int32)
-    qplans = plan_queries(q, global_dfs)
-    shard_plans = []
-    for sid, sh in enumerate(shards):
-        local_dfs = sh.local_dfs
-        run = np.zeros(len(qplans), dtype=bool)
-        routes: list[dict[int, str] | None] = [None] * len(qplans)
-        for i, qp in enumerate(qplans):
-            if qp.allpad:
-                continue
-            if not verified:
-                run[i] = True  # supersets served as-is: no df pruning
-                continue
-            if qp.dead:
-                continue
-            ldfs = [int(local_dfs[t]) for t in qp.terms]
-            est = min(ldfs)
-            if est == 0:  # some term absent on this shard: empty AND here
-                continue
-            run[i] = True
-            hints = {t: r for t in qp.terms if (r := sh.route_term(t, est))}
-            if hints:
-                routes[i] = hints
-        shard_plans.append(ShardPlan(shard_id=sid, run=run, routes=routes))
+    with trace.span("serve.plan", queries=len(q), shards=len(shards)):
+        qplans = plan_queries(q, global_dfs)
+        shard_plans = []
+        for sid, sh in enumerate(shards):
+            local_dfs = sh.local_dfs
+            run = np.zeros(len(qplans), dtype=bool)
+            routes: list[dict[int, str] | None] = [None] * len(qplans)
+            for i, qp in enumerate(qplans):
+                if qp.allpad:
+                    continue
+                if not verified:
+                    run[i] = True  # supersets served as-is: no df pruning
+                    continue
+                if qp.dead:
+                    continue
+                ldfs = [int(local_dfs[t]) for t in qp.terms]
+                est = min(ldfs)
+                if est == 0:  # some term absent on this shard: empty AND here
+                    continue
+                run[i] = True
+                hints = {t: r for t in qp.terms if (r := sh.route_term(t, est))}
+                if hints:
+                    routes[i] = hints
+            shard_plans.append(ShardPlan(shard_id=sid, run=run, routes=routes))
     return BatchPlan(queries=q, qplans=qplans, shard_plans=shard_plans)

@@ -35,6 +35,7 @@ import threading
 import time
 from concurrent.futures import Future
 from dataclasses import dataclass
+from typing import TYPE_CHECKING
 
 import numpy as np
 
@@ -47,6 +48,9 @@ from repro.serve.sched.api import (
     Rejected,
 )
 
+if TYPE_CHECKING:
+    from repro.obs.trace import SpanRef
+
 
 @dataclass(eq=False)  # identity equality: rows are arrays, and each entry is unique
 class Pending:
@@ -58,6 +62,8 @@ class Pending:
     t_submit: float  # monotonic seconds
     deadline: float | None  # absolute monotonic seconds, None = none
     seq: int = 0  # admission order (FIFO tie-break)
+    request: int = 0  # the session's request id (0 when tracing is off)
+    root: "SpanRef | None" = None  # the request's root span while tracing
 
     def resolve(self, outcome) -> None:
         if not self.future.done():

@@ -26,8 +26,10 @@ spawn or respawn — a ``ProcessReplica`` pings the worker's monotonic clock
 onto the host timeline; replies carrying a third element (the worker's span
 buffer and probe records, see sched/worker.py) are ingested into the host
 tracer / probe sink right where the reply lands.  ``ReplicaGroup.call``
-re-activates the configured tracer around the dispatch because it often runs
-on a fan-pool thread that has no ambient tracer of its own.
+re-activates the configured tracer around the dispatch, under the span that
+dispatched it (named by the message's ``TraceContext``), because it often
+runs on a fan-pool thread that has no ambient tracer (and no open span) of
+its own.
 
 Warm snapshots close the respawn compile gap.  A worker process owns every
 jit/Pallas executable its shard ever compiled, so a crash used to mean the
@@ -52,6 +54,7 @@ import numpy as np
 
 from repro.obs import trace
 from repro.obs.collate import estimate_clock_offset, ingest_worker_spans
+from repro.obs.trace import SpanRef
 from repro.serve.sched.api import WorkerFailure
 from repro.serve.sched.worker import execute_bool, execute_topk, worker_main
 
@@ -358,12 +361,16 @@ class ReplicaGroup:
         """Dispatch to the least-loaded replica; retry once (per config) on
         failure, preferring a sibling replica; then raise WorkerFailure.
 
-        Re-activates the session's tracer for the dispatch: multi-shard
-        fan-out runs these calls on pool threads with no ambient tracer, and
-        inline replicas record their spans through it (process replicas ship
-        theirs back instead).
+        Re-activates the session's tracer for the dispatch, under the span
+        the message's ``TraceContext`` names (the session's open
+        ``sched.dispatch``): multi-shard fan-out runs these calls on pool
+        threads with no ambient tracer, and inline replicas record their
+        spans through it, as children of the dispatch (process replicas
+        ship theirs back instead).
         """
         tracer = self.obs.trace if self.obs is not None else None
+        ctx = msg[2] if len(msg) > 2 else None
+        parent = SpanRef(ctx.parent, ctx.parent_depth) if ctx is not None else None
         last: Exception | None = None
         failed = None
         for attempt in range(self.retries + 1):
@@ -372,7 +379,7 @@ class ReplicaGroup:
             )
             replica.inflight += 1
             try:
-                with trace.activate(tracer):
+                with trace.activate(tracer, parent):
                     return replica.call(msg)
             except ReplicaError as e:
                 last = e

@@ -29,8 +29,14 @@ bit-identical to the legacy ``query_*`` entry points, which survive here as
 thin wrappers over ``submit``.
 
 Every decision is observable: ``sched.*`` counters/histograms land in the
-engine's metrics registry and enqueue/queue-wait/batch/dispatch/merge spans
-ride the engine's tracer (repro.obs), so BENCH artifacts explain themselves.
+engine's metrics registry and spans ride the engine's tracer (repro.obs),
+so BENCH artifacts explain themselves.  Each request is one root span,
+``sched.request`` (submit to resolve or reject, with its per-session
+``request`` id and ``outcome``), with ``sched.enqueue`` and
+``sched.queue_wait`` beneath it; each batch is a root ``sched.batch`` that
+lists the ``requests`` it serves, over ``sched.dispatch`` (the shards'
+spans hang under it, on whatever thread runs them), ``sched.merge`` and
+``sched.resolve``.
 With process replicas the trace is *distributed*: a TraceContext travels
 with each fan-out, workers ship their span buffers and probe records back
 with the response, and replicas collate them onto the host timeline in
@@ -53,7 +59,7 @@ import numpy as np
 
 from repro.obs import trace
 from repro.obs.slo import SLOMonitor
-from repro.obs.trace import Span, TraceContext
+from repro.obs.trace import ASYNC_TID, NULL_SPAN, Span, SpanRef, TraceContext
 from repro.rank.score import TopKResult, select_topk
 from repro.serve.sched.admission import AdmissionQueue, Pending
 from repro.serve.sched.api import (
@@ -113,7 +119,7 @@ class Session:
         self._execute_us = self.metrics.histogram("sched.execute_us")
         self._merge_us = self.metrics.histogram("sched.merge_us")
         self.slo = self.cfg.obs.slo if self.cfg.obs.slo is not None else SLOMonitor()
-        self._trace_seq = itertools.count(1)  # trace ids for worker IPC
+        self._request_ids = itertools.count(1)  # drawn only while tracing
         self._store_dir = store_dir  # warm-snapshot home
         self._groups = (
             replica_groups
@@ -330,6 +336,12 @@ class Session:
         """
         fut: Future = Future()
         t_submit = time.monotonic()
+        tracer = self.cfg.obs.trace
+        root, rid = (
+            self._request_span(tracer, fut, req, t_submit)
+            if tracer is not None
+            else (None, 0)
+        )
         if self._closed:
             self._slo_track(fut, req.tenant, t_submit, None)
             fut.set_result(Rejected(reason=REJECT_SHUTDOWN, tenant=req.tenant))
@@ -359,13 +371,49 @@ class Session:
             deadline=(
                 t_submit + deadline_ms / 1e3 if deadline_ms is not None else None
             ),
+            request=rid,
+            root=root,
         )
         self._slo_track(fut, req.tenant, t_submit, pending.deadline)
-        with trace.activate(self.cfg.obs.trace), trace.span(
+        with trace.activate(tracer, root), trace.span(
             "sched.enqueue", mode=req.mode, tenant=req.tenant, priority=req.priority
         ):
             self._queue.offer(pending, block=block)
         return fut
+
+    def _request_span(
+        self, tracer, fut: Future, req: QueryRequest, t_submit: float
+    ) -> tuple[SpanRef, int]:
+        """Reserve the request's root span and record it, ``sched.request``
+        from submit to the future's resolution, when that comes: served,
+        shed or rejected, on whatever thread resolves it.  -> (the root, as
+        the parent of the request's other spans; its request id)."""
+        root = tracer.root()
+        rid = next(self._request_ids)
+
+        def cb(f: Future) -> None:
+            r = f.result()
+            dur_us = 1e6 * (time.monotonic() - t_submit)
+            end_us = (time.perf_counter_ns() - tracer.epoch_ns) / 1e3
+            tracer.add_span(
+                Span(
+                    name="sched.request",
+                    ts_us=end_us - dur_us,
+                    dur_us=dur_us,
+                    tid=ASYNC_TID,
+                    depth=0,
+                    attrs={
+                        "request": rid,
+                        "mode": req.mode,
+                        "tenant": req.tenant,
+                        "outcome": "ok" if r.ok else r.reason,
+                    },
+                    id=root.id,
+                )
+            )
+
+        fut.add_done_callback(cb)
+        return root, rid
 
     def _slo_track(
         self, fut: Future, tenant: str, t_submit: float, deadline: float | None
@@ -420,10 +468,13 @@ class Session:
         self._batches.inc()
         self._batch_size.observe(len(batch))
         self._dispatched.inc(len(batch))
+        tracer = self.cfg.obs.trace
         try:
-            with trace.activate(self.cfg.obs.trace), trace.span(
+            with trace.activate(tracer), trace.span(
                 "sched.batch", mode=mode, size=len(batch)
-            ):
+            ) as sp:
+                if tracer is not None:  # a batch's causal link: its requests
+                    sp.set(requests=[p.request for p in batch])
                 if mode == MODE_BOOLEAN:
                     self._run_boolean(batch, t0)
                 else:
@@ -439,7 +490,8 @@ class Session:
             self._slots.release()
 
     def _queue_wait_spans(self, batch: list[Pending], t0: float) -> None:
-        """Retroactive admission-wait spans: submit -> dispatch per request.
+        """Retroactive admission-wait spans: submit -> dispatch per request,
+        each a child of its request's root span and carrying its id.
 
         ``time.monotonic`` and ``perf_counter`` share CLOCK_MONOTONIC on
         Linux, so the wait interval maps onto the tracer's timeline exactly;
@@ -449,17 +501,23 @@ class Session:
         if tracer is None:
             return
         now_us = (time.perf_counter_ns() - tracer.epoch_ns) / 1e3
-        tid = threading.get_ident()
         for p in batch:
             dur_us = 1e6 * (t0 - p.t_submit)
+            root = p.root or NULL_SPAN  # submitted while tracing was off
             tracer.add_span(
                 Span(
                     name="sched.queue_wait",
                     ts_us=now_us - dur_us,
                     dur_us=dur_us,
-                    tid=tid,
-                    depth=0,
-                    attrs={"tenant": p.req.tenant, "mode": p.req.mode},
+                    tid=ASYNC_TID,
+                    depth=root.depth + 1,
+                    attrs={
+                        "request": p.request,
+                        "tenant": p.req.tenant,
+                        "mode": p.req.mode,
+                    },
+                    id=tracer.next_id(),
+                    parent=root.id,
                 )
             )
 
@@ -471,30 +529,33 @@ class Session:
             q[j, : len(p.row)] = p.row
         return q
 
-    def _fan_out(self, msg) -> list:
+    def _fan_out(self, msg, parent) -> list:
         """One message to every shard group, in parallel when it pays.
 
         Appends a ``TraceContext`` telling workers what telemetry to ship
         back (None when nothing is listening, so the trace-off wire cost
-        stays zero); inline replicas ignore the extra element.
+        stays zero), which names ``parent``, the open ``sched.dispatch``:
+        the shards' spans hang under it, on the pool threads
+        (``ReplicaGroup.call``) and in the workers alike.
         """
-        msg = msg + (self._trace_ctx(),)
+        msg = msg + (self._trace_ctx(parent),)
         if len(self._groups) == 1:
             return [self._groups[0].call(msg)]
         futs = [self._fan.submit(g.call, msg) for g in self._groups]
         return [f.result() for f in futs]  # re-raises WorkerFailure
 
-    def _trace_ctx(self):
+    def _trace_ctx(self, parent):
         obs = self.cfg.obs
         if obs.trace is None and obs.probe_log is None:
             return None
         return TraceContext(
-            trace_id=next(self._trace_seq),
+            parent=parent.id,
+            parent_depth=parent.depth,
             trace=obs.trace is not None,
             probe=obs.probe_log is not None,
         )
 
-    def _ranked_forward_floors(self, batch, items, idxmap) -> list:
+    def _ranked_forward_floors(self, batch, items, idxmap, parent) -> list:
         """Ranked fan-in with the global kth-score floor θ forwarded.
 
         Groups run *sequentially* in ascending doc-range order; each later
@@ -515,7 +576,7 @@ class Session:
                 h = heaps[n]
                 floor = int(h.scores[k - 1]) if h is not None and len(h.scores) == k else 0
                 sent.append((terms, req, k, floor))
-            part = group.call(("topk", sent, self._trace_ctx()))
+            part = group.call(("topk", sent, self._trace_ctx(parent)))
             for n, (terms, req, k, _) in enumerate(items):
                 ids, scores = part[n]
                 if len(ids) == 0:
@@ -558,8 +619,10 @@ class Session:
     def _run_boolean(self, batch: list[Pending], t0: float) -> None:
         q = self._stack_rows(batch, pad_rows=True)  # bucketed probe shape
         t_x0 = time.monotonic()
-        with trace.span("sched.dispatch", shards=len(self._groups), size=len(batch)):
-            parts = self._fan_out(("bool", q))
+        with trace.span(
+            "sched.dispatch", shards=len(self._groups), size=len(batch)
+        ) as sp:
+            parts = self._fan_out(("bool", q), sp)
         t_x1 = time.monotonic()
         words = (self.n_docs + WORD_BITS - 1) // WORD_BITS
         merged = np.zeros((len(batch), words), dtype=np.uint32)
@@ -568,13 +631,14 @@ class Session:
                 off = g.lo // WORD_BITS
                 merged[:, off : off + bm.shape[1]] = bm[: len(batch)]
         phases = self._phase_marks(t0, t_x0, t_x1)
-        for j, p in enumerate(batch):
-            p.resolve(
-                QueryResult(
-                    ids=unpack_row(merged[j], self.n_docs),
-                    **self._timing(p, t0, phases),
+        with trace.span("sched.resolve", size=len(batch)):
+            for j, p in enumerate(batch):
+                p.resolve(
+                    QueryResult(
+                        ids=unpack_row(merged[j], self.n_docs),
+                        **self._timing(p, t0, phases),
+                    )
                 )
-            )
 
     def _run_ranked(self, batch: list[Pending], t0: float) -> None:
         from repro.serve.planner import plan_ranked
@@ -606,11 +670,13 @@ class Session:
             return
         forward = self.sched_cfg.forward_floor and len(self._groups) > 1
         t_x0 = time.monotonic()
-        with trace.span("sched.dispatch", shards=len(self._groups), size=len(items)):
+        with trace.span(
+            "sched.dispatch", shards=len(self._groups), size=len(items)
+        ) as sp:
             if forward:
-                tops = self._ranked_forward_floors(batch, items, idxmap)
+                tops = self._ranked_forward_floors(batch, items, idxmap, sp)
             else:
-                parts = self._fan_out(("topk", items))
+                parts = self._fan_out(("topk", items), sp)
         t_x1 = time.monotonic()
         with trace.span("sched.merge"):
             if not forward:
@@ -621,13 +687,14 @@ class Session:
                     scores = np.concatenate([part[n][1] for part in parts])
                     tops.append(select_topk(ids, scores, int(p.req.k)))
         phases = self._phase_marks(t0, t_x0, t_x1)
-        for top, j in zip(tops, idxmap):
-            p = batch[j]
-            p.resolve(
-                QueryResult(
-                    ids=top.ids, scores=top.scores, **self._timing(p, t0, phases)
+        with trace.span("sched.resolve", size=len(tops)):
+            for top, j in zip(tops, idxmap):
+                p = batch[j]
+                p.resolve(
+                    QueryResult(
+                        ids=top.ids, scores=top.scores, **self._timing(p, t0, phases)
+                    )
                 )
-            )
 
     # ----------------------------------------------------- legacy wrappers
     def query_batch(self, queries: np.ndarray) -> list[np.ndarray]:

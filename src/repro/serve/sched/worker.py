@@ -34,8 +34,9 @@ instead of re-invoking XLA.
 ``ctx`` is an optional ``repro.obs.TraceContext``: when present the reply
 grows a third element, ``("ok", payload, {"spans": [...], "probes": [...]})``
 — the worker's span buffer (drained per request, absolute worker-clock
-nanoseconds) and its routed-probe records, which the host replica maps onto
-its own timeline / probe sink (obs/collate.py).  The worker runs its own
+nanoseconds, its root span's parent the host span ``ctx`` names) and its
+routed-probe records, which the host replica maps onto its own timeline /
+probe sink (obs/collate.py).  The worker runs its own
 ``Tracer`` and an in-memory ``ProbeLog`` either way; with no ctx (or
 ``ctx.trace`` false) nothing extra is recorded or shipped, keeping the
 trace-off wire cost at zero.
@@ -145,7 +146,7 @@ def _build_shard(spec: dict):
 def worker_main(conn, spec: dict) -> None:
     """Entry point of a spawned process replica (see module docstring)."""
     from repro.obs.probelog import ProbeLog
-    from repro.obs.trace import Tracer
+    from repro.obs.trace import SpanRef, Tracer
 
     try:
         shard, cfg = _build_shard(spec)
@@ -180,8 +181,10 @@ def worker_main(conn, spec: dict) -> None:
             elif op in ("bool", "topk"):
                 ctx = msg[2] if len(msg) > 2 else None
                 traced = ctx is not None and ctx.trace
-                with trace.activate(wtracer if traced else None), trace.span(
-                    f"worker.{op}", trace_id=getattr(ctx, "trace_id", 0)
+                # the root hangs under the host span that dispatched it
+                parent = SpanRef(ctx.parent, ctx.parent_depth) if traced else None
+                with trace.activate(wtracer if traced else None, parent), trace.span(
+                    f"worker.{op}"
                 ), plog.context(query=None, shard=shard.shard_id):
                     if op == "bool":
                         payload = execute_bool(shard, msg[1], global_dfs, cfg.verified)

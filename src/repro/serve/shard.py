@@ -357,38 +357,40 @@ class ShardEngine:
         model scoring is one jit dispatch per shard and contends badly when
         issued from concurrent threads, so the facade runs that phase
         serially and fans out only this (numpy probe) phase to its pool.
+        The whole call is one ``shard.execute`` span.
         """
         n_queries = q.shape[0]
-        words = (self.n_docs + WORD_BITS - 1) // WORD_BITS
-        out = np.zeros((n_queries, words), dtype=np.uint32)
-        run = plan.run if plan is not None else None
-        if self.n_docs == 0 or (run is not None and not run.any()):
+        with trace.span("shard.execute", shard=self.shard_id, queries=n_queries):
+            words = (self.n_docs + WORD_BITS - 1) // WORD_BITS
+            out = np.zeros((n_queries, words), dtype=np.uint32)
+            run = plan.run if plan is not None else None
+            if self.n_docs == 0 or (run is not None and not run.any()):
+                return out
+            if mask is None:
+                # worker path (no facade precompute): span the jit probe so a
+                # replica's shipped trace shows model time vs verify time
+                with trace.span(
+                    "shard.candidate_mask", shard=self.shard_id, queries=n_queries
+                ):
+                    mask = self.candidate_mask(q)
+            log = getattr(self.cfg, "probe_log", None)
+            for i in range(n_queries):
+                if run is not None and not run[i]:
+                    continue
+                # probe records inside attribute to (batch-local query i, shard)
+                ctx = log.context(query=i, shard=self.shard_id) if log is not None else NULL_SPAN
+                with ctx, trace.span("shard.verify", shard=self.shard_id, query=i) as sp:
+                    ids = np.nonzero(mask[i])[0].astype(np.int32)
+                    sp.set(candidates=int(len(ids)))
+                    if self.cfg.verified:
+                        if qplans is not None:
+                            routes = plan.routes[i] if plan is not None else None
+                            ids = self._verify_terms(qplans[i].terms, ids, routes)
+                        else:
+                            ids = self._verify(q[i], ids)
+                    sp.set(results=int(len(ids)))
+                out[i] = pack_ids(ids, self.n_docs)
             return out
-        if mask is None:
-            # worker path (no facade precompute): span the jit probe so a
-            # replica's shipped trace shows model time vs verify time
-            with trace.span(
-                "shard.candidate_mask", shard=self.shard_id, queries=n_queries
-            ):
-                mask = self.candidate_mask(q)
-        log = getattr(self.cfg, "probe_log", None)
-        for i in range(n_queries):
-            if run is not None and not run[i]:
-                continue
-            # probe records inside attribute to (batch-local query i, shard)
-            ctx = log.context(query=i, shard=self.shard_id) if log is not None else NULL_SPAN
-            with ctx, trace.span("shard.verify", shard=self.shard_id, query=i) as sp:
-                ids = np.nonzero(mask[i])[0].astype(np.int32)
-                sp.set(candidates=int(len(ids)))
-                if self.cfg.verified:
-                    if qplans is not None:
-                        routes = plan.routes[i] if plan is not None else None
-                        ids = self._verify_terms(qplans[i].terms, ids, routes)
-                    else:
-                        ids = self._verify(q[i], ids)
-                sp.set(results=int(len(ids)))
-            out[i] = pack_ids(ids, self.n_docs)
-        return out
 
     def _kernel_exhaustive(self, q: np.ndarray) -> np.ndarray:
         """Pallas path: per-term packed bitmasks, AND-combined per query."""

@@ -12,6 +12,7 @@ import json
 import os
 import tempfile
 import threading
+import time
 import warnings
 
 import jax
@@ -24,8 +25,8 @@ from repro.data.corpus import synthesize_corpus
 from repro.data.queries import sample_queries, zipf_disjunctions
 from repro.index.build import build_inverted_index
 from repro.obs import (
-    NULL_SPAN, Counter, Gauge, Histogram, ProbeLog, ProbeRecord, Registry,
-    Tracer, trace,
+    ASYNC_TID, NULL_SPAN, Counter, Gauge, Histogram, ProbeLog, ProbeRecord,
+    Registry, Span, SpanRef, Tracer, trace,
 )
 from repro.serve import BooleanEngine, ServeConfig
 
@@ -124,6 +125,109 @@ def test_tracer_reset_clears_spans_and_epoch():
     with tr.activate(), trace.span("y"):
         pass
     assert tr.spans[0].ts_us >= 0.0  # new epoch: timestamps restart near zero
+
+
+def test_spans_carry_ids_and_causal_parents():
+    tr = Tracer()
+    with tr.activate():
+        with trace.span("root"):
+            with trace.span("a"):
+                pass
+            with trace.span("b"):
+                with trace.span("c"):
+                    pass
+    by = {s.name: s for s in tr.spans}
+    assert len({s.id for s in tr.spans}) == 4 and 0 not in {s.id for s in tr.spans}
+    assert by["root"].parent == 0 and by["root"].depth == 0
+    assert by["a"].parent == by["b"].parent == by["root"].id
+    assert by["c"].parent == by["b"].id and by["c"].depth == 2
+
+
+def test_activate_parent_hangs_another_threads_spans_under_it():
+    tr = Tracer()
+    with tr.activate(), trace.span("dispatch") as sp:
+        def work():
+            with trace.activate(tr, sp), trace.span("shard"):
+                with trace.span("verify"):
+                    pass
+            # the base parent leaves with the activation
+            with trace.activate(tr), trace.span("after"):
+                pass
+
+        t = threading.Thread(target=work)
+        t.start()
+        t.join()
+    by = {s.name: s for s in tr.spans}
+    assert by["shard"].tid != by["dispatch"].tid
+    assert by["shard"].parent == by["dispatch"].id and by["shard"].depth == 1
+    assert by["verify"].parent == by["shard"].id and by["verify"].depth == 2
+    assert by["after"].parent == 0 and by["after"].depth == 0
+    # NULL_SPAN and a SpanRef are parents too
+    with trace.activate(tr, NULL_SPAN), trace.span("orphan"):
+        pass
+    with trace.activate(tr, SpanRef(99, 4)), trace.span("remote"):
+        pass
+    by = {s.name: s for s in tr.spans}
+    assert (by["orphan"].parent, by["orphan"].depth) == (0, 0)
+    assert (by["remote"].parent, by["remote"].depth) == (99, 5)
+
+
+def test_chrome_trace_shows_ids_and_async_request_spans():
+    tr = Tracer()
+    root = tr.root()
+    with trace.activate(tr, root), trace.span("child"):
+        pass
+    tr.add_span(Span(name="sched.request", ts_us=0.0, dur_us=50.0, tid=ASYNC_TID,
+                     depth=0, attrs={"request": 1}, id=root.id))
+    doc = tr.chrome_trace()
+    [child] = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    assert child["args"] == {"id": 2, "parent": root.id}
+    begin, end = [e for e in doc["traceEvents"] if e["ph"] in ("b", "e")]
+    assert (begin["ph"], end["ph"]) == ("b", "e") and begin["id"] == end["id"] == root.id
+    assert begin["args"] == {"request": 1, "id": root.id, "parent": 0}
+    assert end["ts"] == 50.0 and doc["otherData"]["n_spans"] == 2
+    json.dumps(doc)
+
+
+def test_trace_off_draws_no_span_id():
+    tr = Tracer()
+    with trace.activate(None, SpanRef(5, 0)):
+        assert trace.span("x") is NULL_SPAN
+        with trace.span("x"):
+            pass
+    assert tr.spans == [] and tr.next_id() == 1
+
+
+def test_profiler_host_clock_keeps_a_constant_offset_to_perf_counter(tmp_path):
+    """The traced benchmark maps program spans onto the profiler's clock
+    with one marker: two markers 0.2 s apart must agree within 50 us."""
+    import glob
+
+    from jax.profiler import ProfileData, TraceAnnotation
+
+    bracket = {}
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for name in ("clock.a", "clock.b"):
+            t0 = time.perf_counter_ns()
+            with TraceAnnotation(name):
+                bracket[name] = (t0, time.perf_counter_ns())
+            time.sleep(0.2)
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    seen = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name in bracket:
+                    seen[e.name] = int(e.start_ns)
+    (a0, a1), (b0, b1) = bracket["clock.a"], bracket["clock.b"]
+    traced = seen["clock.b"] - seen["clock.a"]
+    # each marker starts inside its bracket of perf_counter_ns readings
+    slack = 50_000 + (a1 - a0) + (b1 - b0)
+    assert abs(traced - ((b0 + b1) - (a0 + a1)) / 2) <= slack
+    assert 0.19e9 < traced < 0.5e9
 
 
 # ---------------------------------------------------------------- metrics

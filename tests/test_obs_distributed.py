@@ -9,7 +9,8 @@ snapshot/reset race under writer threads.
 
 The integration half runs real process replicas: worker spans must merge
 into the host tracer time-aligned (own pid lanes, no partial overlaps,
-trace_id threaded through), worker probe records must land in the host
+each worker root a child of the host span that dispatched it), worker
+probe records must land in the host
 sink, a crashed-then-respawned replica must re-sync its clock offset, and
 ``QueryResult.autopsy()`` / ``Session.slo_report()`` must decompose where
 the latency went.
@@ -41,7 +42,8 @@ from repro.obs import (
     render_prometheus,
     write_prometheus,
 )
-from repro.obs.trace import Span
+from repro.obs import trace
+from repro.obs.trace import Span, SpanRef
 from repro.serve import BooleanEngine, QueryRequest, Rejected, ServeConfig, Session
 from repro.serve.sched import MODE_RANKED, WorkerFailure
 
@@ -104,6 +106,23 @@ def test_wire_span_round_trip_rebases_onto_host_epoch():
     assert lanes == {4242}
     assert {"name": "process_name", "ph": "M", "pid": 4242, "tid": 0,
             "args": {"name": "replica"}} in doc["traceEvents"]
+
+
+def test_ingested_worker_spans_take_host_ids_and_keep_the_root_parent():
+    host, worker = Tracer(name="host"), Tracer(name="w")
+    with host.activate(), host.span("sched.batch"), host.span("sched.dispatch") as sp:
+        # the worker's ids restart at 1: its child takes the dispatch's id
+        with trace.activate(worker, SpanRef(sp.id, sp.depth)), \
+                trace.span("worker.bool"), trace.span("shard.verify"):
+            pass
+    wire = worker.drain_wire()
+    assert {d["id"] for d in wire} == {1, 2} and sp.id == 2
+    assert ingest_worker_spans(host, wire, offset_ns=0, pid=77) == 2
+    by = {s_.name: s_ for s_ in host.spans}
+    assert len({s_.id for s_ in host.spans}) == 4
+    assert by["worker.bool"].parent == by["sched.dispatch"].id
+    assert by["shard.verify"].parent == by["worker.bool"].id
+    assert [by[n].depth for n in ("sched.dispatch", "worker.bool", "shard.verify")] == [1, 2, 3]
 
 
 def _span(name, ts, dur, *, pid=0, tid=0):
@@ -293,7 +312,8 @@ def system():
 def test_worker_spans_merge_time_aligned(system, tmp_path):
     """The tentpole end to end: a ranked + boolean request through a real
     process replica produces ONE coherent timeline — worker spans on their
-    own pid lane, clock-aligned, nested, carrying the request's trace_id."""
+    own pid lane, clock-aligned, nested, each worker root a child of the
+    host's sched.dispatch."""
     corpus, inv, li_cfg, lb = system
     tracer, plog = Tracer(), ProbeLog()
     cfg = ServeConfig(n_shards=2, sched=dict(n_replicas=1),
@@ -331,9 +351,20 @@ def test_worker_spans_merge_time_aligned(system, tmp_path):
         assert t0_us - 1e3 <= s_.ts_us <= s_.ts_us + s_.dur_us <= t1_us + 1e3
     assert nesting_violations(tracer.spans, slack_us=0.5) == []
 
-    # the request's trace_id threads through to the worker-root spans
+    # the dispatching span's id threads through to the worker-root spans,
+    # and every worker span hangs under its reply's root by host ids
+    by_id = {s_.id: s_ for s_ in tracer.spans}
+    assert len(by_id) == len(tracer.spans)  # ids unique across pid lanes
     roots = [s_ for s_ in worker if s_.name in ("worker.bool", "worker.topk")]
-    assert roots and all(s_.attrs.get("trace_id", 0) > 0 for s_ in roots)
+    assert roots
+    for r_ in roots:
+        up = by_id[r_.parent]
+        assert up.pid == 0 and up.name == "sched.dispatch"
+        assert r_.depth == up.depth + 1
+    for s_ in worker:
+        if s_ not in roots:
+            up = by_id[s_.parent]
+            assert up.pid == s_.pid and s_.depth == up.depth + 1
 
     # worker probe records were forwarded into the host sink
     assert plog.n_records > 0
@@ -409,7 +440,9 @@ def test_short_circuit_results_have_autopsy_defaults():
 def test_trace_context_pickles_and_defaults():
     import pickle
 
-    ctx = TraceContext(trace_id=5, trace=True, probe=False)
+    ctx = TraceContext(parent=5, parent_depth=1, trace=True, probe=False)
     back = pickle.loads(pickle.dumps(ctx))
-    assert back == ctx and back.trace_id == 5
-    assert TraceContext() == TraceContext(trace_id=0, trace=False, probe=False)
+    assert back == ctx and (back.parent, back.parent_depth) == (5, 1)
+    assert TraceContext() == TraceContext(
+        parent=0, parent_depth=-1, trace=False, probe=False
+    )

@@ -651,3 +651,121 @@ def test_warm_snapshot_respawn_bit_identical_and_re_jit_free(system, tmp_path):
         for a, b in zip(want, got2):
             assert np.array_equal(a.ids, b.ids)
             assert np.array_equal(a.scores, b.scores)
+
+
+# ------------------------------------------------------------- causal spans
+@pytest.fixture(scope="module")
+def traced_session(system):
+    """4 inline shards through a traced Session, the served cell's path:
+    concurrent Boolean requests, one ranked request, one shed request."""
+    from repro.obs import Tracer
+
+    tracer = Tracer()
+    eng = _engine(system, n_shards=4, obs=dict(trace=tracer))
+    q, rq = _queries(system)
+    with Session(eng) as s:
+        futs = [s.submit_async(QueryRequest(terms=row)) for row in q]
+        futs.append(s.submit_async(QueryRequest(terms=rq[0], mode=MODE_RANKED, k=3)))
+        futs.append(s.submit_async(QueryRequest(terms=q[0], deadline_ms=-1.0)))
+        results = [f.result(timeout=30) for f in futs]
+    return tracer, results
+
+
+def _chain(by_id, span):
+    out = []
+    while span.parent:
+        span = by_id[span.parent]
+        out.append(span.name)
+    return out
+
+
+def test_shard_spans_hang_under_dispatch_and_batch(traced_session):
+    tracer, results = traced_session
+    assert [r.ok for r in results] == [True] * (len(results) - 1) + [False]
+    by_id = {s.id: s for s in tracer.spans}
+    assert len(by_id) == len(tracer.spans)  # ids unique per tracer
+    shard = [s for s in tracer.spans
+             if s.name in ("shard.verify", "shard.candidate_mask")]
+    assert {s.name for s in shard} == {"shard.verify", "shard.candidate_mask"}
+    dispatch_tids = {s.tid for s in tracer.spans if s.name == "sched.dispatch"}
+    # recorded on the fan pool's threads, yet below the runner's dispatch
+    assert any(s.tid not in dispatch_tids for s in shard)
+    for s in shard:
+        chain = _chain(by_id, s)
+        assert chain[:2] == ["shard.execute", "sched.dispatch"], chain
+        assert chain[-1] == "sched.batch" and s.depth == 3
+
+
+def test_every_span_is_one_deeper_than_its_parent(traced_session):
+    tracer, _ = traced_session
+    by_id = {s.id: s for s in tracer.spans}
+    for s in tracer.spans:
+        if s.parent == 0:
+            assert s.depth == 0, s
+        else:
+            assert s.depth == by_id[s.parent].depth + 1, s
+    assert {"serve.plan", "shard.execute", "sched.resolve", "sched.request",
+            "decode.postings"} <= {s.name for s in tracer.spans}
+
+
+def test_one_request_span_per_request_with_its_queue_wait(traced_session):
+    tracer, results = traced_session
+    reqs = [s for s in tracer.spans if s.name == "sched.request"]
+    assert len(reqs) == len(results)  # one each, served or shed
+    ids = sorted(s.attrs["request"] for s in reqs)
+    assert ids == list(range(1, len(results) + 1))
+    by_req = {s.attrs["request"]: s for s in reqs}
+    waits = [s for s in tracer.spans if s.name == "sched.queue_wait"]
+    for w in waits:
+        root = by_req[w.attrs["request"]]
+        assert w.parent == root.id and w.depth == 1
+        assert root.ts_us <= w.ts_us + 1.0
+    served = [s for s in reqs if s.attrs["outcome"] == "ok"]
+    assert len(served) == sum(r.ok for r in results)
+    assert sorted(w.attrs["request"] for w in waits) == sorted(
+        s.attrs["request"] for s in served)
+    [shed] = [s for s in reqs if s.attrs["outcome"] != "ok"]
+    assert shed.attrs["outcome"] == REJECT_DEADLINE and shed.depth == 0
+    # a batch's causal link is the list of requests it serves
+    batched = [r for s in tracer.spans if s.name == "sched.batch"
+               for r in s.attrs["requests"]]
+    assert sorted(batched) == sorted(s.attrs["request"] for s in served)
+
+
+def test_serve_plan_once_per_plan_batch_call(system, monkeypatch):
+    from repro.obs import Tracer
+    from repro.serve import boolean, planner
+
+    calls = []
+
+    def counting(*a, _orig=planner.plan_batch, **kw):
+        calls.append(1)
+        return _orig(*a, **kw)
+
+    monkeypatch.setattr(planner, "plan_batch", counting)  # Session's shards
+    monkeypatch.setattr(boolean, "plan_batch", counting)  # the facade
+    q, _ = _queries(system)
+    tracer = Tracer()
+    eng = _engine(system, n_shards=4, obs=dict(trace=tracer))
+    eng.query_batch(q[:3])
+    assert len(calls) == 1
+    with Session(eng) as s:
+        s.query_batch(q[3:6])
+    plans = [s for s in tracer.spans if s.name == "serve.plan"]
+    assert len(plans) == len(calls) > 1
+    batches = [s for s in tracer.spans if s.name == "sched.batch"]
+    assert len(calls) == 1 + 4 * len(batches)  # each shard plans its batch
+
+
+def test_trace_off_session_records_no_span_and_draws_no_id(system):
+    from repro.obs import Tracer
+
+    tracer = Tracer()
+    eng = _engine(system, n_shards=4, obs=dict(trace=tracer))
+    eng.cfg.obs.trace = None
+    q, _ = _queries(system)
+    with Session(eng) as s:
+        got = s.query_batch(q[:4])
+        shed = s.submit(QueryRequest(terms=q[0], deadline_ms=-1.0))
+    assert len(got) == 4 and not shed.ok
+    assert tracer.spans == [] and tracer.next_id() == 1
