@@ -18,6 +18,11 @@ guarantee robust to that drift at negligible false-positive cost.
 
 No false negatives ⇒ Boolean results are supersets; `verified` mode
 re-checks survivors against tier-2 for exactness (see algorithms.py).
+
+A LearnedBloom is the served model: it holds its term table in the packed
+serving layout (``membership.pack_term_table``), packed once when it is
+made; packing a packed table is a no-op, so a bloom rebuilt from another's
+params, or sliced from it, keeps the one packed array.
 """
 from __future__ import annotations
 
@@ -41,6 +46,9 @@ class LearnedBloom:
     tau: np.ndarray  # (n_terms,) float32 per-term zero-FN threshold
     backup_keys: np.ndarray  # sorted int64 keys t*n_docs+d spilled to exact storage
     n_docs: int
+
+    def __post_init__(self):
+        self.params = membership.pack_term_table(self.params)
 
     def size_bits(self, embed_bits: int = 32) -> int:
         te = self.params["term_embed"]["table"]

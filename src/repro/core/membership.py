@@ -10,6 +10,16 @@ Params are a plain pytree; `axes` is the twin logical-sharding pytree:
   term table  -> ("terms",  None)   sharded over `model`
   doc table   -> ("docs",   None)   sharded over `data` (+pod)
 so scoring f(q, all docs) is doc-parallel with a bitmap all-gather at the end.
+
+Served layout.  Training holds the term table as (n_terms, E).  The served
+model (``LearnedBloom``) holds it packed by ``pack_term_table``: r = 128 // E
+term rows side by side in each 128-lane row, (ceil(n_terms / r), 128), the
+tail zero-padded.  A TPU keeps a narrow (n_terms, 64) table with the terms
+on the lanes, so a row gather from it first relayouts the whole table; the
+packed table is lane-dense and a gather reads it where it lies.  Same f32
+values, same bytes.  Every reader of term rows goes through ``term_rows``,
+which finds r from the shapes (packed width / doc-embedding width), so one
+code path serves both layouts.
 """
 from __future__ import annotations
 
@@ -17,6 +27,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.common.config import LearnedIndexConfig
 from repro.common import nn
@@ -42,9 +53,56 @@ def init_membership(
     return params, axes
 
 
+LANES = 128  # a TPU vreg's lane count: the packed term table's row width
+
+
+def term_rows_per_lane_row(params: Any) -> int:
+    """Term rows held side by side in one row of the term table (1: unpacked)."""
+    return params["term_embed"]["table"].shape[1] // params["doc_embed"]["table"].shape[1]
+
+
+def pack_term_table(params: Any) -> Any:
+    """Params with the term table packed to 128-lane rows (see module doc).
+
+    Applies only where the embedding width E is below 128 and divides it;
+    otherwise, or when the table is packed already, returns ``params``.
+    Packs on the host (a row-major reshape, no program to compile) and
+    puts a device table back on the device."""
+    table = params["term_embed"]["table"]
+    n, e = table.shape
+    if term_rows_per_lane_row(params) != 1 or e >= LANES or LANES % e:
+        return params
+    r = LANES // e
+    host = np.asarray(table)
+    if n % r:
+        host = np.concatenate([host, np.zeros((r - n % r, e), host.dtype)])
+    host = host.reshape(-1, LANES)
+    packed = host if isinstance(table, np.ndarray) else jnp.asarray(host)
+    return {**params, "term_embed": {**params["term_embed"], "table": packed}}
+
+
+def term_rows(params: Any, terms: jax.Array) -> jax.Array:
+    """(..., E) embedding rows of ``terms``, from either table layout.
+
+    A packed table is gathered at row t // r; lane slice t % r is picked by
+    static slices and selects, so each row is bit-identical to the unpacked
+    table's."""
+    table = params["term_embed"]["table"]
+    r = term_rows_per_lane_row(params)
+    if r == 1:
+        return jnp.take(table, terms, axis=0)
+    e = table.shape[1] // r
+    rows = jnp.take(table, terms // r, axis=0)
+    lane = (terms % r)[..., None]
+    out = rows[..., :e]
+    for j in range(1, r):
+        out = jnp.where(lane == j, rows[..., j * e : (j + 1) * e], out)
+    return out
+
+
 def pair_logits(params: Any, terms: jax.Array, docs: jax.Array) -> jax.Array:
     """f-logit for aligned (term, doc) id vectors — the training path."""
-    te = nn.embed(params["term_embed"], terms)
+    te = term_rows(params, terms)
     de = nn.embed(params["doc_embed"], docs)
     if "mlp" in params:
         h = jnp.concatenate([te, de], axis=-1)
@@ -59,7 +117,7 @@ def term_doc_logits(params: Any, terms: jax.Array, doc_tile: jax.Array | None = 
     against the (doc-sharded) embedding table. kernels/membership provides the
     fused Pallas version that also packs the thresholded bitmask.
     """
-    te = nn.embed(params["term_embed"], terms)  # (Q, E)
+    te = term_rows(params, terms)  # (Q, E)
     dt = params["doc_embed"]["table"]
     if doc_tile is not None:
         dt = jnp.take(dt, doc_tile, axis=0)

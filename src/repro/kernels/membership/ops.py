@@ -8,6 +8,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.membership import term_rows
 from repro.kernels.membership.kernel import D_BLK, LANE, Q_BLK, membership_bitmask
 
 
@@ -28,7 +29,7 @@ def score_terms_bitmask(
     interpret: bool | None = None,
 ) -> jax.Array:
     """(Q,) term ids -> (Q, ceil(D/32)) packed membership bitmask."""
-    te = jnp.take(params["term_embed"]["table"], terms, axis=0)
+    te = term_rows(params, terms)
     de = params["doc_embed"]["table"]
     tq = jnp.take(tau, terms)
     n_docs = de.shape[0]

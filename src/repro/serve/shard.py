@@ -32,6 +32,7 @@ import numpy as np
 from repro.common.config import LearnedIndexConfig
 from repro.core import algorithms as alg
 from repro.core.learned_bloom import LearnedBloom
+from repro.core.membership import term_rows_per_lane_row
 from repro.index.build import InvertedIndex, slice_index
 from repro.index.intersect import gallop_membership
 from repro.obs import trace
@@ -473,6 +474,11 @@ class ShardEngine:
         if reg is None:
             reg = Registry()
             reg.register("range", lambda: {"lo": int(self.lo), "hi": int(self.hi)})
+            # 1 = unpacked; r > 1 = the served term table holds r rows per lane row
+            reg.register(
+                "membership",
+                lambda: {"term_rows_per_lane_row": term_rows_per_lane_row(self.state.params)},
+            )
             reg.register(
                 "decode_cache",
                 lambda: self._decode_cache.stats(),

@@ -1,5 +1,7 @@
 """The paper's core: membership model, learned Bloom guarantees, Algorithms
 1-3 correctness, Eq.(2) gain estimator."""
+from types import SimpleNamespace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,6 +10,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.common.config import CorpusConfig, LearnedIndexConfig
 from repro.core import (
+    LearnedBloom,
     build_engine,
     estimate_gain,
     exhaustive_query,
@@ -23,6 +26,7 @@ from repro.core import (
     term_doc_logits,
     two_tier_guaranteed,
 )
+from repro.core.membership import pack_term_table, term_rows, term_rows_per_lane_row
 from repro.data.corpus import synthesize_corpus
 from repro.data.queries import brute_force_answers, sample_queries
 from repro.index.build import build_inverted_index
@@ -61,6 +65,71 @@ def test_term_doc_matches_pair_logits(setup):
         docs = jnp.arange(corpus.n_docs, dtype=jnp.int32)
         pl = pair_logits(params, jnp.full((corpus.n_docs,), t, jnp.int32), docs)
         np.testing.assert_allclose(np.asarray(full[i]), np.asarray(pl), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("embed_dim", [32, 64, 128, 96])
+def test_term_rows_match_unpacked_table(embed_dim):
+    """Packed or not (96 does not divide 128; 128 is lane-wide already), every
+    row read through term_rows is bit-identical to the training table's,
+    padded tail included (1001 terms divide by no r > 1)."""
+    n_terms = 1001
+    cfg = LearnedIndexConfig(embed_dim=embed_dim)
+    params, _ = init_membership(jax.random.key(3), cfg, n_terms, 40)
+    packed = pack_term_table(params)
+    r = 128 // embed_dim if 128 % embed_dim == 0 else 1
+    assert term_rows_per_lane_row(packed) == r
+    assert packed["term_embed"]["table"].shape == (-(-n_terms // r), r * embed_dim)
+    if r == 1:
+        assert packed is params
+    table = np.asarray(params["term_embed"]["table"])
+    terms = jnp.arange(n_terms, dtype=jnp.int32)
+    assert np.array_equal(np.asarray(term_rows(packed, terms)), table)
+    grid = jnp.asarray([[1000, 0, 7], [3, 998, 513]], jnp.int32)
+    got = jax.jit(term_rows)(packed, grid)
+    assert np.array_equal(np.asarray(got), table[np.asarray(grid)])
+
+
+@pytest.mark.parametrize("algorithm", ["block", "exhaustive", "two_tier"])
+def test_packed_table_masks_match_unpacked(setup, algorithm):
+    """The served (packed) model gives the unpacked model's masks exactly."""
+    _, inv, params, lb, eng, queries, _ = setup
+    assert params["term_embed"]["table"].shape == (1500, 16)  # training keeps its layout
+    assert term_rows_per_lane_row(lb.params) == 8
+    served = build_engine(lb.params, lb.tau, inv, truncation_k=K, block_size=BLOCK)
+    assert np.array_equal(
+        run_queries(served, queries, algorithm), run_queries(eng, queries, algorithm)
+    )
+
+
+def test_packing_a_packed_bloom_is_a_no_op(setup):
+    from repro.serve.sched.session import _numpy_tree
+
+    _, _, _, lb, _, _, _ = setup
+    table = lb.params["term_embed"]["table"]
+    assert pack_term_table(lb.params) is lb.params
+    again = LearnedBloom(params=lb.params, tau=lb.tau, backup_keys=lb.backup_keys, n_docs=lb.n_docs)
+    assert again.params["term_embed"]["table"] is table
+    # a process replica rebuilds its bloom from a numpy copy of the params
+    rebuilt = LearnedBloom(
+        params=_numpy_tree(lb.params), tau=lb.tau, backup_keys=lb.backup_keys, n_docs=lb.n_docs
+    )
+    assert np.array_equal(rebuilt.params["term_embed"]["table"], np.asarray(table))
+
+
+def test_packing_keeps_the_model_bytes(setup):
+    """Size and held bytes equal the unpacked model's, apart from the one
+    padded packed row (1500 terms, 8 per row: 4 rows of zeros)."""
+    _, _, params, lb, _, _, _ = setup
+    row_bytes = 128 * 4
+    # what the bloom's size would read over the training table
+    before = LearnedBloom.size_bits(
+        SimpleNamespace(params=params, tau=lb.tau, backup_keys=lb.backup_keys)
+    )
+    assert 0 <= lb.size_bits() - before < row_bytes * 8
+    nbytes = lambda tree: sum(int(x.nbytes) for x in jax.tree.leaves(tree))  # noqa: E731
+    assert 0 <= nbytes(lb.params) - nbytes(params) < row_bytes
+    table = np.asarray(lb.params["term_embed"]["table"]).reshape(-1, 16)
+    assert not table[1500:].any()
 
 
 def test_exhaustive_is_superset(setup):

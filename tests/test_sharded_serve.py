@@ -192,6 +192,22 @@ def test_serving_stats_aggregation(system):
     assert summary["probe_bytes"] >= 0
 
 
+def test_shards_share_the_packed_term_table(system):
+    """The global bloom and all four shard slices hold one packed term table,
+    and each shard reports it through its membership gauge."""
+    corpus, inv, li_cfg, lb = system
+    table = lb.params["term_embed"]["table"]
+    assert table.shape == (1600 // 8, 128)
+    eng = BooleanEngine(lb, inv, li_cfg, ServeConfig(n_shards=4))
+    assert len(eng.shards) == 4
+    for sh in eng.shards:
+        assert sh.lb.params["term_embed"]["table"] is table
+        assert sh.state.params["term_embed"]["table"] is table
+    eng.metrics.reset()  # a gauge of the layout, not of a window: kept
+    stats = eng.metrics.snapshot()
+    assert [s["membership"]["term_rows_per_lane_row"] for s in stats["shards"]] == [8] * 4
+
+
 def test_planner_skips_shards_missing_terms(system):
     """A shard where some query term has zero local df must not run it."""
     corpus, inv, li_cfg, lb = system

@@ -6,15 +6,20 @@ which refuses what interpret-mode tests cannot see — illegal block shapes,
 Mosaic ops without a TPU lowering, VMEM overruns.  Widths are the chip
 smoke's (``chip_smoke.py``): the robust vocabulary of 60,000 terms, shards
 of 13,200 documents, 64-wide embeddings, batches of 16 queries of up to 8
-terms, k = 10 and k = 100.
+terms, k = 10 and k = 100.  The term table is given in the served layout
+(``membership.pack_term_table``), and the programs that gather its rows are
+checked to read it where it lies, with no copy of the whole table.
 
 The topology is described inside a module fixture (only the worker that
 runs these tests loads the TPU library), and the tests skip where it
 cannot be described.  The persistent compilation cache is off around them:
 an entry compiled for a described chip cannot be read back without one.
 """
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -52,6 +57,30 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _served_params(sharding):
+    """Membership params as the served model holds them: the term table
+    packed by the program's own pack function (on host zeros, for its
+    shape), one smoke shard's docs."""
+    from repro.core.membership import pack_term_table
+
+    params = {
+        "term_embed": {"table": np.zeros((N_TERMS, EMBED), np.float32)},
+        "doc_embed": {"table": np.zeros((SHARD_DOCS, EMBED), np.float32)},
+        "bias": np.zeros((), np.float32),
+    }
+    packed = pack_term_table(params)
+    return jax.tree.map(lambda x: _spec(sharding, x.shape, x.dtype), packed)
+
+
+def _table_copies(hlo: str, params) -> list[str]:
+    """``copy(`` instructions whose result is the whole term table, whatever
+    its layout and whatever feeds them (the parameter or its prefetch): a
+    relayout of the table on every call."""
+    rows, cols = params["term_embed"]["table"].shape
+    result = re.compile(rf"=\s*f32\[{rows},{cols}\]\{{[^}}]*\}}\s+copy\(")
+    return [line.strip()[:160] for line in hlo.splitlines() if result.search(line)]
+
+
 @pytest.mark.parametrize(
     "C,W,k",
     [(128, 1, 10), (16384, 32, 100)],
@@ -79,6 +108,7 @@ def test_fused_topk_compiles(one_chip, C, W, k):
 
 def test_membership_bitmask_compiles(one_chip):
     from repro.kernels.membership.kernel import D_BLK, Q_BLK, membership_bitmask
+    from repro.kernels.membership.ops import score_terms_bitmask
 
     d = -(-SHARD_DOCS // D_BLK) * D_BLK
     q = -(-QUERIES * MAX_TERMS // Q_BLK) * Q_BLK
@@ -89,6 +119,14 @@ def test_membership_bitmask_compiles(one_chip):
         s((q,), jnp.float32), s((), jnp.float32),
     )
     assert "tpu_custom_call" in hlo
+    # the whole wrapper, term-row gather included, over the served table
+    params = _served_params(one_chip)
+    hlo = _compile(
+        lambda p, t, tau: score_terms_bitmask(p, t, tau, interpret=False),
+        params, s((QUERIES * MAX_TERMS,), jnp.int32), s((N_TERMS,), jnp.float32),
+    )
+    assert "tpu_custom_call" in hlo
+    assert _table_copies(hlo, params) == []
 
 
 def test_guided_probe_batch_compiles(one_chip):
@@ -130,22 +168,20 @@ def test_dense_topk_compiles(one_chip):
     )
 
 
-def test_block_query_compiles(one_chip):
-    """Algorithm 3 over one smoke shard: the Boolean candidate program."""
+@pytest.mark.parametrize("Q", [1, QUERIES], ids=["Q1", f"Q{QUERIES}"])
+def test_block_query_compiles(one_chip, Q):
+    """Algorithm 3 over one smoke shard: the Boolean candidate program, for
+    a lone request and a full batch (each batch size is its own program)."""
     from repro.core.algorithms import block_query
 
     block = 128
     n_blocks = -(-SHARD_DOCS // block)
     words = -(-n_blocks // 32)
     s = lambda shape, dt: _spec(one_chip, shape, dt)  # noqa: E731
-    params = {
-        "term_embed": {"table": s((N_TERMS, EMBED), jnp.float32)},
-        "doc_embed": {"table": s((SHARD_DOCS, EMBED), jnp.float32)},
-        "bias": s((), jnp.float32),
-    }
-    _compile(
+    params = _served_params(one_chip)
+    hlo = _compile(
         lambda b, p, t, q: block_query(b, p, t, q, n_docs=SHARD_DOCS, block_size=block),
         s((N_TERMS, words), jnp.uint32), params, s((N_TERMS,), jnp.float32),
-        s((QUERIES, MAX_TERMS), jnp.int32),
+        s((Q, MAX_TERMS), jnp.int32),
     )
-
+    assert _table_copies(hlo, params) == []
